@@ -15,6 +15,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.cache import enable_compile_cache
+
 from . import (bench_adaptive, bench_async, bench_bounds, bench_comm_time,
                bench_compression, bench_engine, bench_kernels,
                bench_lm_protocol, bench_population, bench_rff,
@@ -47,6 +49,7 @@ def main() -> None:
     ap.add_argument("--json-dir", default=None, metavar="DIR",
                     help="also write BENCH_<suite>.json reports here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(SUITES)
     all_rows, failures = [], []

@@ -82,7 +82,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import substrate as substrate_mod
@@ -429,6 +428,12 @@ def init_protocol_carry(sub: Substrate, m: int):
     return state0, ref0, sub.ledger_init(m)
 
 
+def _row_sums(a: np.ndarray) -> np.ndarray:
+    """Sum over the last (learner) axis in one order whatever the
+    array's memory order (:func:`assemble_sim_result`)."""
+    return np.ascontiguousarray(a).sum(axis=-1)
+
+
 def assemble_sim_result(sub: Substrate, record_divergence: bool,
                         loss: np.ndarray, err: np.ndarray,
                         round_bytes: np.ndarray, div: np.ndarray,
@@ -440,13 +445,17 @@ def assemble_sim_result(sub: Substrate, record_divergence: bool,
     cross-learner sum happens HERE, identically for every execution
     mode — numpy's pairwise float32 sum over identical per-learner
     values — which is what makes the mesh-sharded engine and the
-    serving path bit-for-bit with the single-device scan.  Divergence
+    serving path bit-for-bit with the single-device scan.  numpy's
+    summation order follows the array's memory order, and a device
+    array can arrive column-major (the TPU returns the scan's (T, m)
+    outputs that way, while the serving path stacks rows), so the sum
+    runs over a row-major copy.  Divergence
     and eps series are dropped when not recorded / not produced,
     matching the substrate's ``free_divergence`` / ``has_eps`` flags.
     """
     keep_div = record_divergence or sub.free_divergence
     return SimResult.from_round_series(
-        loss.sum(axis=1), err.sum(axis=1), round_bytes,
+        _row_sums(loss), _row_sums(err), round_bytes,
         div if keep_div else np.zeros((0,)),
         flags,
         eps if sub.has_eps else np.zeros((0,)))
@@ -564,12 +573,12 @@ def _sharded_core(sub: Substrate, kind: str, record_divergence: bool,
     in_specs = (P(), P(lead), P(lead), P(), data_spec, data_spec)
     if masked:
         in_specs = in_specs + (P(None, lead),)   # participation (T, m)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(series_spec, series_spec, scalar_spec, scalar_spec,
                    scalar_spec, scalar_spec),
-        check_rep=False)
+        check_vma=False)
 
     if masked:
         def simulate(params: ScanParams, X: Array, Y: Array, part: Array):
@@ -828,7 +837,7 @@ def sweep(
         outs = fn(params, Xg, Yg)
         lo, er, nb, dv, fl, ep = (np.asarray(o) for o in outs)
         # (n, T, m) per-learner series -> (n, T), summed exactly as in run
-        losses[idx], errors[idx], flags[idx] = lo.sum(-1), er.sum(-1), fl
+        losses[idx], errors[idx], flags[idx] = _row_sums(lo), _row_sums(er), fl
         round_bytes[idx], divs[idx], eps[idx] = nb, dv, ep
 
     keep_div = record_divergence or all(s.free_divergence for s in subs)

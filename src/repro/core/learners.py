@@ -40,6 +40,7 @@ from .rkhs import (
     empty_model,
     insert_sv,
     predict,
+    rowsum,
     scale_model,
 )
 
@@ -186,8 +187,8 @@ def kernel_update_from_yhat(
 
     kxx = {
         "gaussian": jnp.asarray(1.0, jnp.float32),
-        "linear": jnp.sum(x * x),
-        "poly": (jnp.sum(x * x) + cfg.kernel.coef0) ** cfg.kernel.degree,
+        "linear": rowsum(x * x),
+        "poly": (rowsum(x * x) + cfg.kernel.coef0) ** cfg.kernel.degree,
     }[cfg.kernel.kind]
 
     if cfg.algo == "kernel_sgd":
@@ -228,16 +229,17 @@ def linear_update(
     cfg: LearnerConfig, state: LinearLearnerState, example: Tuple[Array, Array]
 ) -> Tuple[LinearLearnerState, Array]:
     x, y = example
-    # multiply + reduce, not a dot: keeps the float result independent
-    # of the learner-axis layout (see rkhs.predict / DESIGN.md Sec. 9)
-    yhat = jnp.sum(state.w * x) + state.b
+    # multiply + fixed-order sum, not a dot: keeps the float result
+    # independent of the learner-axis layout (rkhs.rowsum, DESIGN.md
+    # Sec. 9)
+    yhat = rowsum(state.w * x) + state.b
     ell, g = _loss_and_grad(cfg.loss, yhat, y)
 
     if cfg.algo == "linear_sgd":
         w = (1.0 - cfg.eta * cfg.lam) * state.w - cfg.eta * g * x
         b = state.b - cfg.eta * g
     else:  # linear_pa
-        tau_pa = jnp.minimum(cfg.C, ell / jnp.maximum(jnp.sum(x * x) + 1.0, 1e-12))
+        tau_pa = jnp.minimum(cfg.C, ell / jnp.maximum(rowsum(x * x) + 1.0, 1e-12))
         direction = y if cfg.loss == "hinge" else -jnp.sign(yhat - y)
         w = state.w + tau_pa * direction * x
         b = state.b + tau_pa * direction

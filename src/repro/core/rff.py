@@ -28,6 +28,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from .rkhs import rowsum
+
 Array = jnp.ndarray
 
 
@@ -50,9 +52,10 @@ def featurize(spec: RFFSpec, W: Array, b: Array, X: Array) -> Array:
     """phi(X): (..., d) -> (..., D).  Pure-jnp reference; see
     repro.kernels.ops.rff_features for the Pallas path.
 
-    The projection is an explicit multiply + last-axis reduce rather
-    than ``X @ W.T``: a row's result is then independent of how many
-    rows share the call, which is what lets the mesh-sharded engine
+    The projection is an explicit multiply + fixed-order last-axis sum
+    (``rkhs.rowsum``) rather than ``X @ W.T``: a row's result is then
+    independent of how many rows share the call, which is what lets
+    the mesh-sharded engine
     (one learner slice per device) reproduce the single-device engine
     bit-for-bit (DESIGN.md Sec. 9 — XLA's gemm kernels pick
     row-count-dependent accumulation orders, gemv vs gemm).  The
@@ -62,7 +65,7 @@ def featurize(spec: RFFSpec, W: Array, b: Array, X: Array) -> Array:
     lead = tuple(range(X.ndim - 1))     # explicit broadcast of the
     Wx = jnp.expand_dims(W, lead)       # (D, d) params over X's batch
     bx = jnp.expand_dims(b, lead)       # axes (rank promotion is off)
-    proj = jnp.sum(X[..., None, :] * Wx, axis=-1) + bx
+    proj = rowsum(X[..., None, :] * Wx) + bx
     return jnp.sqrt(2.0 / spec.num_features) * jnp.cos(proj)
 
 
@@ -85,7 +88,7 @@ def make_update(spec: RFFSpec, W: Array, bias: Array, *, eta: float = 0.5,
     def update(state: RFFLearnerState, example):
         x, y = example
         z = featurize(spec, W, bias, x[None])[0]
-        yhat = jnp.sum(state.w * z) + state.b
+        yhat = rowsum(state.w * z) + state.b
         if loss == "hinge":
             ell = jnp.maximum(0.0, 1.0 - y * yhat)
             g = jnp.where(ell > 0, -y, 0.0)

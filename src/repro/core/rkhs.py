@@ -55,6 +55,13 @@ class KernelSpec:
             raise ValueError(f"unknown kernel {self.kind!r}")
 
 
+def _matmul(A: Array, B: Array) -> Array:
+    """A @ B with float32 products on every backend: the TPU's DEFAULT
+    precision would round float32 operands to bfloat16."""
+    # reprolint: allow[DET01] the bulk-Gram oracle's product; the bitwise path is _gram_rows
+    return jnp.matmul(A, B, precision=jax.lax.Precision.HIGHEST)
+
+
 def gram(spec: KernelSpec, X: Array, Y: Array) -> Array:
     """Dense Gram matrix K[i, j] = k(X[i], Y[j]).  Pure-jnp reference.
 
@@ -65,13 +72,13 @@ def gram(spec: KernelSpec, X: Array, Y: Array) -> Array:
     X = X.astype(jnp.float32)
     Y = Y.astype(jnp.float32)
     if spec.kind == "linear":
-        return X @ Y.T  # reprolint: allow[DET01] bulk-Gram oracle; the bitwise path is _gram_rows
+        return _matmul(X, Y.T)  # reprolint: allow[DET01] bulk-Gram oracle; the bitwise path is _gram_rows
     if spec.kind == "poly":
-        return (X @ Y.T + spec.coef0) ** spec.degree  # reprolint: allow[DET01] bulk-Gram oracle
+        return (_matmul(X, Y.T) + spec.coef0) ** spec.degree  # reprolint: allow[DET01] bulk-Gram oracle
     # gaussian
     xx = jnp.sum(X * X, axis=-1)[:, None]
     yy = jnp.sum(Y * Y, axis=-1)[None, :]
-    sq = jnp.maximum(xx + yy - 2.0 * (X @ Y.T), 0.0)  # reprolint: allow[DET01] bulk-Gram oracle
+    sq = jnp.maximum(xx + yy - 2.0 * _matmul(X, Y.T), 0.0)  # reprolint: allow[DET01] bulk-Gram oracle
     return jnp.exp(-spec.gamma * sq)
 
 
@@ -122,13 +129,39 @@ def active_mask(f: SVModel) -> Array:
     return f.sv_id >= 0
 
 
+def rowsum(v: Array) -> Array:
+    """Sum over the last axis in one fixed pairwise order.
+
+    The axis is zero-padded to a power of two and halved by elementwise
+    adds, so no reduce op is emitted and a row's float result cannot
+    depend on the other axes.  An XLA reduce picks its accumulation
+    order from the whole operand shape: on the CPU (jax 0.9) a
+    last-axis reduce of 5-8 elements rounds differently when the
+    operand holds one or two rows than when it holds four or more,
+    which is what a mesh shard of m/n learners does to an (m, d) sum
+    (DESIGN.md Sec. 9).  Sums over a model's feature axis — the raw
+    inputs' d, or an RFF model's D features — go through here; the SV
+    budget-axis sums stay reduces (budgets are >= 9 wide, where no
+    such dependence was found).
+    """
+    n = v.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    if width != n:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, width - n)])
+    while width > 1:
+        width //= 2
+        v = v[..., :width] + v[..., width:]
+    return v[..., 0]
+
+
 def num_active(f: SVModel) -> Array:
     return jnp.sum(active_mask(f).astype(jnp.int32))
 
 
 def _gram_rows(spec: KernelSpec, X: Array, Y: Array) -> Array:
-    """``gram`` with the cross term as an explicit multiply + last-axis
-    reduce instead of ``X @ Y.T``.  Same formula (gaussian still uses
+    """``gram`` with the cross term as an explicit multiply + fixed-order
+    feature-axis sum (:func:`rowsum`) instead of ``X @ Y.T``.  Same
+    formula (gaussian still uses
     xx + yy - 2<x,y>), but a row's floats no longer depend on how many
     rows share the call: XLA's gemm/gemv kernels pick row-count-
     dependent accumulation orders, and the prediction path must be
@@ -139,13 +172,13 @@ def _gram_rows(spec: KernelSpec, X: Array, Y: Array) -> Array:
     """
     X = X.astype(jnp.float32)
     Y = Y.astype(jnp.float32)
-    cross = jnp.sum(X[:, None, :] * Y[None, :, :], axis=-1)
+    cross = rowsum(X[:, None, :] * Y[None, :, :])
     if spec.kind == "linear":
         return cross
     if spec.kind == "poly":
         return (cross + spec.coef0) ** spec.degree
-    xx = jnp.sum(X * X, axis=-1)[:, None]
-    yy = jnp.sum(Y * Y, axis=-1)[None, :]
+    xx = rowsum(X * X)[:, None]
+    yy = rowsum(Y * Y)[None, :]
     sq = jnp.maximum(xx + yy - 2.0 * cross, 0.0)
     return jnp.exp(-spec.gamma * sq)
 

@@ -237,13 +237,13 @@ def run_linear_simulation(
     @jax.jit
     def dists_to(st, ref):
         return jax.vmap(
-            lambda s: jnp.sum((s.w - ref.w) ** 2) + (s.b - ref.b) ** 2
+            lambda s: rkhs.rowsum((s.w - ref.w) ** 2) + (s.b - ref.b) ** 2
         )(st)
 
     @jax.jit
     def diverg(st):
         wbar = jnp.mean(st.w, axis=0); bbar = jnp.mean(st.b)
-        return jnp.mean(jnp.sum((st.w - wbar[None, :]) ** 2, -1)
+        return jnp.mean(rkhs.rowsum((st.w - wbar[None, :]) ** 2)
                         + (st.b - bbar) ** 2)
 
     @jax.jit
@@ -258,9 +258,9 @@ def run_linear_simulation(
     total_loss = 0.0; total_err = 0.0
     nparams = d + 1
 
-    # multiply + reduce, matching the substrate layer's layout-
-    # independent prediction floats (rkhs.predict rationale)
-    vpredict = jax.jit(jax.vmap(lambda s, x: jnp.sum(s.w * x) + s.b))
+    # multiply + fixed-order sum, matching the substrate layer's
+    # layout-independent prediction floats (rkhs.rowsum rationale)
+    vpredict = jax.jit(jax.vmap(lambda s, x: rkhs.rowsum(s.w * x) + s.b))
 
     for t in range(T):
         xb = jnp.asarray(X[t]); yb = jnp.asarray(Y[t])

@@ -670,13 +670,13 @@ class _PrimalSubstrate(Substrate):
 
     def dist_to_ref(self, models, ref) -> Array:
         return jax.vmap(
-            lambda s: jnp.sum((s.w - ref.w) ** 2) + (s.b - ref.b) ** 2
+            lambda s: rkhs.rowsum((s.w - ref.w) ** 2) + (s.b - ref.b) ** 2
         )(models)
 
     def divergence(self, models) -> Array:
         wbar = jnp.mean(models.w, axis=0)
         bbar = jnp.mean(models.b)
-        return jnp.mean(jnp.sum((models.w - wbar[None, :]) ** 2, -1)
+        return jnp.mean(rkhs.rowsum((models.w - wbar[None, :]) ** 2)
                         + (models.b - bbar) ** 2)
 
     def sync_payload(self, models, ledger):
@@ -734,7 +734,7 @@ class _PrimalSubstrate(Substrate):
                 * jnp.asarray(self.num_params * 4, jnp.int32))
 
     def dist_one(self, model, ref) -> Array:
-        return jnp.sum((model.w - ref.w) ** 2) + (model.b - ref.b) ** 2
+        return rkhs.rowsum((model.w - ref.w) ** 2) + (model.b - ref.b) ** 2
 
     def upload_payload(self, bm, state, known):
         return (state, set(),
@@ -822,9 +822,9 @@ class LinearSubstrate(_PrimalSubstrate):
         return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
 
     def predict(self, models, x: Array) -> Array:
-        # multiply + reduce, not a dot — layout-independent floats
-        # (rkhs.predict has the full rationale; DESIGN.md Sec. 9)
-        return jnp.sum(models.w * x, axis=-1) + models.b
+        # multiply + fixed-order sum, not a dot — layout-independent
+        # floats (rkhs.rowsum has the full rationale; DESIGN.md Sec. 9)
+        return rkhs.rowsum(models.w * x) + models.b
 
     def update(self, state, example):
         return jax.vmap(functools.partial(learners.update, self.lcfg))(
@@ -854,7 +854,7 @@ class LinearSubstrate(_PrimalSubstrate):
         return learners.update(self.lcfg, state, example)
 
     def predict_one(self, model, x: Array) -> Array:
-        return jnp.sum(model.w * x) + model.b
+        return rkhs.rowsum(model.w * x) + model.b
 
     def init_reference(self):
         return learners.init_linear_state(self.lcfg)
@@ -926,7 +926,7 @@ class RFFSubstrate(_PrimalSubstrate):
 
     def predict(self, models, x: Array) -> Array:
         Z = self._phi(x)                               # (m, D)
-        return jnp.sum(models.w * Z, axis=-1) + models.b
+        return rkhs.rowsum(models.w * Z) + models.b
 
     def predict_batch(self, models, lids: Array, Xb: Array) -> Array:
         # featurize the whole bucket in one _phi call (the feature map
@@ -934,10 +934,10 @@ class RFFSubstrate(_PrimalSubstrate):
         # weights; per-row floats match predict_one because featurize
         # and the dot are row-independent multiply+reduce ops.
         Z = self._phi(Xb)                              # (n, D)
-        return jnp.sum(models.w[lids] * Z, axis=-1) + models.b[lids]
+        return rkhs.rowsum(models.w[lids] * Z) + models.b[lids]
 
     def _round_with_features(self, st, z, y):
-        yhat = jnp.sum(st.w * z) + st.b   # layout-independent floats
+        yhat = rkhs.rowsum(st.w * z) + st.b   # layout-independent floats
         ell, g = learners.loss_and_grad(self.loss, yhat, y)
         w = (1.0 - self.eta * self.lam) * st.w - self.eta * g * z
         b = st.b - self.eta * g
@@ -971,7 +971,7 @@ class RFFSubstrate(_PrimalSubstrate):
         # unfused: one shared featurize (instead of the composed
         # path's two), the exact predict expression, the exact update
         Z = self._phi(x)                               # (m, D)
-        yhat = jnp.sum(state.w * Z, axis=-1) + state.b
+        yhat = rkhs.rowsum(state.w * Z) + state.b
         new_state, ell = jax.vmap(self._update_with_features)(state, Z, y)
         return new_state, ell, yhat
 
@@ -985,7 +985,7 @@ class RFFSubstrate(_PrimalSubstrate):
 
     def predict_one(self, model, x: Array) -> Array:
         z = self._phi(x[None])[0]
-        return jnp.sum(model.w * z) + model.b
+        return rkhs.rowsum(model.w * z) + model.b
 
     # the feature map dominates a node round: featurize once, share it
     # between the service-error prediction and the update

@@ -21,10 +21,13 @@ with, resolved in three tiers:
    (128) clipped to the padded operand, recorded in the cache with
    ``source="default"``.
 3. **measured search (TPU)** — on a real TPU backend, and only when
-   not called mid-trace (a search launches kernels; doing that while
+   no operand is a tracer (a search launches kernels; doing that while
    tracing an outer jit would nest tracers), each aligned candidate
-   tile is timed via the caller-provided ``measure`` thunk and the
-   fastest wins (``source="search"``).
+   tile is compiled by the caller-provided ``compile_candidate`` and
+   timed, and the fastest wins (``source="search"``).  A candidate the
+   TPU compiler refuses (a tiling it cannot lower, more VMEM than the
+   kernel may use) is skipped; if it refuses every candidate the
+   search raises instead of hiding a kernel that cannot run.
 
 The cache is process-local on purpose.  Persisting tunings across
 processes would couple benchmark runs to stale machine profiles; a
@@ -73,13 +76,12 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _tracing() -> bool:
-    """True when called under an active jax trace — searching would
-    launch kernels inside the outer tracer, so the resolver must not."""
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:   # future jax: assume eager only when provable
-        return True
+def _tracing(operands: Sequence[object]) -> bool:
+    """True when any operand is a tracer: the op is being traced into an
+    outer jit/vmap/scan, where a search would launch kernels inside the
+    outer trace, so the resolver must not."""
+    return any(isinstance(x, jax.core.Tracer)
+               for x in jax.tree.leaves(operands))
 
 
 def candidates_for(size: int) -> Tuple[int, ...]:
@@ -96,6 +98,12 @@ def default_blocks(dims: Sequence[int]) -> Tuple[int, ...]:
     return tuple(candidates_for(s)[0] for s in dims)
 
 
+# What the TPU compiler raises when it refuses one candidate: the Pallas
+# lowering's ValueError (block shape / tiling) or the compile's runtime
+# error (e.g. scoped VMEM exceeded).
+_REFUSALS = (ValueError, jax.errors.JaxRuntimeError)
+
+
 def _time_thunk(thunk: Callable[[], object]) -> float:
     jax.block_until_ready(thunk())          # compile + warm
     t0 = time.perf_counter()
@@ -110,16 +118,19 @@ def tuned_blocks(
     *,
     dtype: str = "float32",
     kind: str = "",
-    measure: Optional[Callable[[Tuple[int, ...]], object]] = None,
+    compile_candidate: Optional[
+        Callable[[Tuple[int, ...]], Callable[[], object]]] = None,
+    operands: Sequence[object] = (),
 ) -> Tuple[int, ...]:
     """The tile sizes to launch ``op`` with for operand extents ``dims``
     (one entry per blocked axis).
 
-    ``measure(blocks)`` — when provided and the backend is a real TPU —
-    must run the kernel once with the given tile assignment and return
-    a value to block on; the resolver times each candidate assignment
-    and caches the winner.  Off-TPU (interpret mode) or mid-trace the
-    defaults are cached without any launch.
+    ``compile_candidate(blocks)`` — when provided and the backend is a
+    real TPU — must compile the kernel with the given tile assignment
+    and return a thunk that launches it and returns a value to block
+    on; the resolver times each candidate assignment and caches the
+    winner.  Off-TPU (interpret mode) or when any of ``operands`` is a
+    tracer, the defaults are cached without any launch.
     """
     key = TileKey(op=op, dims=tuple(int(s) for s in dims),
                   dtype=str(dtype), kind=str(kind))
@@ -127,23 +138,28 @@ def tuned_blocks(
     if hit is not None:
         return hit.blocks
 
-    if measure is None or not _on_tpu() or _tracing():
+    if (compile_candidate is None or not _on_tpu()
+            or _tracing(operands)):
         choice = TileChoice(default_blocks(key.dims), "default")
         _CACHE[key] = choice
         return choice.blocks
 
     best, best_t = None, float("inf")
+    refused = []
     for blocks in _grid(tuple(candidates_for(s) for s in key.dims)):
         try:
-            t = _time_thunk(lambda: measure(blocks))
-        except Exception:        # a candidate may exceed VMEM — skip it
+            thunk = compile_candidate(blocks)
+        except _REFUSALS as e:   # e.g. a tile that exceeds VMEM
+            refused.append(f"{blocks}: {e}")
             continue
+        t = _time_thunk(thunk)
         if t < best_t:
             best, best_t = blocks, t
-    if best is None:             # every candidate failed: fall back
-        choice = TileChoice(default_blocks(key.dims), "default")
-    else:
-        choice = TileChoice(best, "search")
+    if best is None:
+        raise RuntimeError(
+            f"the TPU compiler refused every tile candidate for {key}:\n"
+            + "\n".join(refused))
+    choice = TileChoice(best, "search")
     _CACHE[key] = choice
     return choice.blocks
 

@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BN = 128
 DEFAULT_BM = 128
+_LANE = 128          # TPU lane width: lane-dense output rows
 
 
 def _kernel_row(x, sv, *, kind: str, gamma: float, degree: int,
@@ -47,6 +48,7 @@ def _kernel_row(x, sv, *, kind: str, gamma: float, degree: int,
     cross = jax.lax.dot_general(
         x, sv, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )                                              # (1, bn)
     if kind == "linear":
         return cross
@@ -60,18 +62,20 @@ def _kernel_row(x, sv, *, kind: str, gamma: float, degree: int,
 def _sv_predict_kernel(x_ref, sv_ref, a_ref, o_ref, *, kind: str,
                        gamma: float, degree: int, coef0: float):
     j = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)             # (1, d)
-    sv = sv_ref[...][0].astype(jnp.float32)        # (bn, d)
-    a = a_ref[...].astype(jnp.float32)             # (1, bn)
+    x = x_ref[0].astype(jnp.float32)               # (1, d)
+    sv = sv_ref[0].astype(jnp.float32)             # (bn, d)
+    a = a_ref[0].astype(jnp.float32)               # (1, bn)
     k = _kernel_row(x, sv, kind=kind, gamma=gamma, degree=degree,
                     coef0=coef0)
-    partial_val = jnp.sum(k * a)
+    partial_val = jnp.sum(k * a, axis=1, keepdims=True)   # (1, 1)
 
     @pl.when(j == 0)
     def _init():
-        o_ref[0, 0] = 0.0
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[0, 0] += partial_val
+    # Mosaic stores vectors only: every lane of the (1, LANE) output row
+    # carries the same running sum, and the wrapper reads lane 0.
+    o_ref[0] += partial_val
 
 
 def sv_predict_pallas(
@@ -86,10 +90,16 @@ def sv_predict_pallas(
     block_n: int = DEFAULT_BN,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """(B, 1) fused masked predictions; N, d pre-padded (N % block_n
+    """(B,) fused masked predictions; N, d pre-padded (N % block_n
     == 0).  Grid (B, N/bn): the budget axis streams through VMEM and
-    accumulates into one scalar per learner — rows are independent
-    grid cells, so per-row floats don't depend on B."""
+    accumulates into one value per learner — rows are independent
+    grid cells, so per-row floats don't depend on B.
+
+    X and A travel as (B, 1, ·) so that the last two dims of every
+    block either equal the array's or are (8, 128)-aligned, which the
+    TPU lowering requires; the output is a lane-dense (B, 1, LANE)
+    slab for the same reason.
+    """
     B, N, d = SV.shape
     assert X.shape == (B, d) and A.shape == (B, N), (X.shape, SV.shape,
                                                      A.shape)
@@ -97,18 +107,19 @@ def sv_predict_pallas(
     kernel = functools.partial(
         _sv_predict_kernel, kind=kind, gamma=gamma, degree=degree,
         coef0=coef0)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, N // block_n),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, block_n, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, block_n), lambda i, j: (i, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, _LANE), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, _LANE), jnp.float32),
         interpret=interpret,
-    )(X, SV, A)
+    )(X.reshape(B, 1, d), SV, A.reshape(B, 1, N))
+    return out[:, 0, 0]
 
 
 def _loss_grad(loss: str, yhat, y):
@@ -142,6 +153,7 @@ def _rff_step_kernel(x_ref, y_ref, w_ref, b_ref, wf_ref, bias_ref,
     proj = jax.lax.dot_general(
         x, wf, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )                                               # (bm, D) on the MXU
     z = scale * jnp.cos(proj + bias)
     w_new, b_new, ell, yhat = _primal_step_math(

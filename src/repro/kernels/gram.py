@@ -42,6 +42,7 @@ def _gram_kernel(x_ref, y_ref, o_ref, *, kind: str, gamma: float,
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )                                            # (bm, bn) on the MXU
     if kind == "linear":
         o_ref[...] = cross

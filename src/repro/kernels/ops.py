@@ -5,10 +5,10 @@ Responsibilities:
   the feature dim of every kernel kind; padded rows/cols are cropped
   from outputs, and padded alpha/beta entries are zero so quadform is
   exact);
-- choose interpret mode automatically off-TPU (this container is
-  CPU-only: interpret=True executes the kernel bodies in Python so the
-  TPU kernels are validated for correctness here and compiled for real
-  on TPU);
+- choose interpret mode off-TPU: on a CPU backend interpret=True
+  executes the kernel bodies in Python, which is how the test suite
+  checks the TPU kernels' numerics; on a TPU backend they compile to
+  Mosaic (tests/test_tpu_compile.py compiles them for a described v5e);
 - resolve block sizes through kernels/autotune.py (the seed-era
   hardcoded 128s are now the *defaults* the tuner falls back to; pass
   explicit ``block_*`` ints to bypass it);
@@ -45,6 +45,15 @@ _LANE = 128          # TPU lane width: last-dim alignment
 _MIN_PALLAS = 128    # below this, use the jnp reference
 
 LAUNCH_COUNTS: collections.Counter = collections.Counter()
+
+# The backend-parity tolerance: one pinned pair for every pallas-vs-
+# reference comparison (tests/conftest.py, chip_smoke.py).  The kernels
+# accumulate in fp32 with a tile order that differs from the jnp
+# oracles, so values agree to a few ULP-amplified rounding steps; rtol
+# covers the large-magnitude RKHS distances, atol the near-zero hinge
+# margins.
+PARITY_RTOL = 1e-3
+PARITY_ATOL = 5e-3
 
 
 def engages(*dims) -> bool:
@@ -145,11 +154,10 @@ def _sv_predict_call(X, SV, A, *, kind, gamma, degree, coef0, block_n,
     Xp = _pad_to(X, 1, _LANE)
     SVp = _pad_to(_pad_to(SV, 1, block_n), 2, _LANE)
     Ap = _pad_to(A, 1, block_n)
-    out = sv_predict_pallas(
+    return sv_predict_pallas(
         Xp, SVp, Ap, kind=kind, gamma=gamma, degree=degree, coef0=coef0,
         block_n=block_n, interpret=interpret,
     )
-    return out[:, 0]
 
 
 @functools.partial(
@@ -185,6 +193,19 @@ def _primal_step_call(X, Yl, w, b, W, bias, *, scale, loss, eta, lam,
 # ---------------------------------------------------------------------------
 
 
+def _tuned(op, dims, dtype, key_kind, launcher, args, statics):
+    """Tiles for one launch of ``launcher(*args, **statics(blocks))``,
+    through the autotuner: a candidate is compiled from these exact
+    operands, so the search times the launch it will make."""
+    def compile_candidate(blocks):
+        exe = launcher.lower(*args, **statics(blocks)).compile()
+        return lambda: exe(*args)
+
+    return autotune.tuned_blocks(op, dims, dtype=str(dtype), kind=key_kind,
+                                 compile_candidate=compile_candidate,
+                                 operands=args)
+
+
 def gram(X, Y, *, kind="gaussian", gamma=1.0, degree=3, coef0=1.0,
          block_m=None, block_n=None, force_pallas=False):
     """K(X, Y): (M, d), (N, d) -> (M, N) fp32."""
@@ -193,17 +214,17 @@ def gram(X, Y, *, kind="gaussian", gamma=1.0, degree=3, coef0=1.0,
         return ref.gram_ref(X, Y, kind=kind, gamma=gamma, degree=degree,
                             coef0=coef0)
 
-    def launch(blocks):
-        return _gram_call(X, Y, kind=kind, gamma=gamma, degree=degree,
-                          coef0=coef0, block_m=blocks[0], block_n=blocks[1],
-                          interpret=_interpret())
+    def statics(blocks):
+        return dict(kind=kind, gamma=gamma, degree=degree, coef0=coef0,
+                    block_m=blocks[0], block_n=blocks[1],
+                    interpret=_interpret())
 
     if block_m is None or block_n is None:
-        block_m, block_n = autotune.tuned_blocks(
-            "gram", (M, N), dtype=str(X.dtype),
-            kind=f"{kind}:d={X.shape[1]}", measure=launch)
+        block_m, block_n = _tuned("gram", (M, N), X.dtype,
+                                  f"{kind}:d={X.shape[1]}", _gram_call,
+                                  (X, Y), statics)
     LAUNCH_COUNTS["gram"] += 1
-    return launch((block_m, block_n))
+    return _gram_call(X, Y, **statics((block_m, block_n)))
 
 
 def rff_features(X, W, b, *, num_features=None, block_m=None, block_d=None,
@@ -214,16 +235,16 @@ def rff_features(X, W, b, *, num_features=None, block_m=None, block_d=None,
     if not force_pallas and not engages(M, D):
         return ref.rff_ref(X, W, b, num_features=nf)
 
-    def launch(blocks):
-        return _rff_call(X, W, b, num_features=nf, block_m=blocks[0],
-                         block_d=blocks[1], interpret=_interpret())
+    def statics(blocks):
+        return dict(num_features=nf, block_m=blocks[0], block_d=blocks[1],
+                    interpret=_interpret())
 
     if block_m is None or block_d is None:
-        block_m, block_d = autotune.tuned_blocks(
-            "rff", (M, D), dtype=str(X.dtype), kind=f"d={X.shape[1]}",
-            measure=launch)
+        block_m, block_d = _tuned("rff", (M, D), X.dtype,
+                                  f"d={X.shape[1]}", _rff_call, (X, W, b),
+                                  statics)
     LAUNCH_COUNTS["rff"] += 1
-    return launch((block_m, block_d))
+    return _rff_call(X, W, b, **statics((block_m, block_d)))
 
 
 def quadform(X, Y, alpha, beta, *, kind="gaussian", gamma=1.0, degree=3,
@@ -234,17 +255,17 @@ def quadform(X, Y, alpha, beta, *, kind="gaussian", gamma=1.0, degree=3,
         return ref.quadform_ref(X, Y, alpha, beta, kind=kind, gamma=gamma,
                                 degree=degree, coef0=coef0)
 
-    def launch(blocks):
-        return _quadform_call(X, Y, alpha, beta, kind=kind, gamma=gamma,
-                              degree=degree, coef0=coef0, block_m=blocks[0],
-                              block_n=blocks[1], interpret=_interpret())
+    def statics(blocks):
+        return dict(kind=kind, gamma=gamma, degree=degree, coef0=coef0,
+                    block_m=blocks[0], block_n=blocks[1],
+                    interpret=_interpret())
 
     if block_m is None or block_n is None:
-        block_m, block_n = autotune.tuned_blocks(
-            "quadform", (M, N), dtype=str(X.dtype),
-            kind=f"{kind}:d={X.shape[1]}", measure=launch)
+        block_m, block_n = _tuned("quadform", (M, N), X.dtype,
+                                  f"{kind}:d={X.shape[1]}", _quadform_call,
+                                  (X, Y, alpha, beta), statics)
     LAUNCH_COUNTS["quadform"] += 1
-    return launch((block_m, block_n))
+    return _quadform_call(X, Y, alpha, beta, **statics((block_m, block_n)))
 
 
 def rkhs_dist_sq(X, Y, alpha, beta, *, kind="gaussian", gamma=1.0,
@@ -278,17 +299,15 @@ def sv_predict(X, SV, A, *, kind="gaussian", gamma=1.0, degree=3,
         return ref.sv_predict_ref(X, SV, A, kind=kind, gamma=gamma,
                                   degree=degree, coef0=coef0)
 
-    def launch(blocks):
-        return _sv_predict_call(X, SV, A, kind=kind, gamma=gamma,
-                                degree=degree, coef0=coef0,
-                                block_n=blocks[0], interpret=_interpret())
+    def statics(blocks):
+        return dict(kind=kind, gamma=gamma, degree=degree, coef0=coef0,
+                    block_n=blocks[0], interpret=_interpret())
 
     if block_n is None:
-        (block_n,) = autotune.tuned_blocks(
-            "sv_predict", (N,), dtype=str(SV.dtype),
-            kind=f"{kind}:d={d}", measure=launch)
+        (block_n,) = _tuned("sv_predict", (N,), SV.dtype, f"{kind}:d={d}",
+                            _sv_predict_call, (X, SV, A), statics)
     LAUNCH_COUNTS["sv_predict"] += 1
-    return launch((block_n,))
+    return _sv_predict_call(X, SV, A, **statics((block_n,)))
 
 
 def fused_primal_step(X, Yl, w, b, *, W=None, bias=None, scale=1.0,
@@ -310,18 +329,18 @@ def fused_primal_step(X, Yl, w, b, *, W=None, bias=None, scale=1.0,
         return ref.primal_step_ref(X, Yl, w, b, W=W, bias=bias, scale=scale,
                                    loss=loss, eta=eta, lam=lam)
 
-    def launch(blocks):
-        return _primal_step_call(X, Yl, w, b, W, bias, scale=scale,
-                                 loss=loss, eta=eta, lam=lam,
-                                 block_m=blocks[0], featurize=featurize,
-                                 interpret=_interpret())
+    def statics(blocks):
+        return dict(scale=scale, loss=loss, eta=eta, lam=lam,
+                    block_m=blocks[0], featurize=featurize,
+                    interpret=_interpret())
 
+    args = (X, Yl, w, b, W, bias)
     if block_m is None:
-        (block_m,) = autotune.tuned_blocks(
-            op, (B,), dtype=str(X.dtype),
-            kind=f"d={X.shape[1]}:D={D}:{loss}", measure=launch)
+        (block_m,) = _tuned(op, (B,), X.dtype,
+                            f"d={X.shape[1]}:D={D}:{loss}",
+                            _primal_step_call, args, statics)
     LAUNCH_COUNTS[op] += 1
-    return launch((block_m,))
+    return _primal_step_call(*args, **statics((block_m,)))
 
 
 # ---------------------------------------------------------------------------

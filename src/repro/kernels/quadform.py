@@ -14,7 +14,9 @@ O(M N) — on a v5e (819 GB/s HBM) this turns the divergence check from
 memory-bound to compute-bound for typical budgets.
 
 TPU grid iterations execute sequentially, so cross-step accumulation
-into the output ref is safe; the first step initializes it.
+into the output ref is safe; the first step initializes it.  The
+accumulator is a lane-dense (1, 128) row, not a (1, 1) scalar: the
+TPU lowering cannot store a scalar to VMEM.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
+_LANE = 128          # TPU lane width: lane-dense output row
 
 
 def _quadform_kernel(x_ref, y_ref, a_ref, b_ref, o_ref, *, kind: str,
@@ -41,6 +44,7 @@ def _quadform_kernel(x_ref, y_ref, a_ref, b_ref, o_ref, *, kind: str,
     cross = jax.lax.dot_general(
         x, y, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     if kind == "linear":
         k = cross
@@ -55,9 +59,11 @@ def _quadform_kernel(x_ref, y_ref, a_ref, b_ref, o_ref, *, kind: str,
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        o_ref[0, 0] = 0.0
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[0, 0] += partial_val
+    # Mosaic stores vectors only: every lane of the (1, LANE) output
+    # carries the same running sum, and the wrapper reads lane 0.
+    o_ref[...] += partial_val
 
 
 def quadform_pallas(
@@ -89,8 +95,8 @@ def quadform_pallas(
             pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
             pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, _LANE), lambda i, j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, _LANE), jnp.float32),
         interpret=interpret,
     )(X, Y, alpha.reshape(1, M), beta.reshape(1, N))
     return out[0, 0]

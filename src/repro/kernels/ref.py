@@ -8,11 +8,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# float32 products on the chip too: the TPU's DEFAULT precision rounds
+# float32 matmul operands to bfloat16
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def gram_ref(X, Y, *, kind="gaussian", gamma=1.0, degree=3, coef0=1.0):
     X = X.astype(jnp.float32)
     Y = Y.astype(jnp.float32)
-    cross = X @ Y.T  # reprolint: allow[DET01] bulk oracle, compared under PARITY_RTOL
+    cross = jnp.matmul(X, Y.T, precision=_F32)  # reprolint: allow[DET01] bulk oracle, compared under PARITY_RTOL
     if kind == "linear":
         return cross
     if kind == "poly":
@@ -28,14 +32,16 @@ def rff_ref(X, W, b, *, num_features=None):
     W = W.astype(jnp.float32)
     D = num_features or W.shape[0]
     # reprolint: allow[DET01] bulk oracle, compared under PARITY_RTOL
-    return jnp.sqrt(2.0 / D) * jnp.cos(X @ W.T + b.astype(jnp.float32)[None, :])
+    return jnp.sqrt(2.0 / D) * jnp.cos(jnp.matmul(X, W.T, precision=_F32)
+                                       + b.astype(jnp.float32)[None, :])
 
 
 def quadform_ref(X, Y, alpha, beta, *, kind="gaussian", gamma=1.0,
                  degree=3, coef0=1.0):
     K = gram_ref(X, Y, kind=kind, gamma=gamma, degree=degree, coef0=coef0)
     # reprolint: allow[DET01] bulk oracle, compared under PARITY_RTOL
-    return alpha.astype(jnp.float32) @ K @ beta.astype(jnp.float32)
+    return jnp.matmul(jnp.matmul(alpha.astype(jnp.float32), K, precision=_F32),
+                      beta.astype(jnp.float32), precision=_F32)
 
 
 def sv_predict_ref(X, SV, A, *, kind="gaussian", gamma=1.0, degree=3,
@@ -75,7 +81,8 @@ def primal_step_ref(X, Yl, w, b, *, W=None, bias=None, scale=1.0,
     b = b.astype(jnp.float32)
     if W is not None:
         # reprolint: allow[DET01] bulk oracle, compared under PARITY_RTOL
-        z = scale * jnp.cos(X @ W.T.astype(jnp.float32)
+        z = scale * jnp.cos(jnp.matmul(X, W.T.astype(jnp.float32),
+                                       precision=_F32)
                             + bias.astype(jnp.float32)[None, :])
     else:
         z = X

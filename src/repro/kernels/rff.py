@@ -27,6 +27,7 @@ def _rff_kernel(x_ref, w_ref, b_ref, o_ref, *, scale: float):
     proj = jax.lax.dot_general(
         x, w, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )                                             # (bm, bd)
     o_ref[...] = scale * jnp.cos(proj + b)
 

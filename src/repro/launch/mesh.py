@@ -12,12 +12,20 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the repo places
+    arrays with explicit NamedShardings and shard_map specs and lets the
+    compiler propagate the rest (jax >= 0.7 defaults to ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -25,7 +33,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     the distributed-protocol integration tests."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_learner_mesh(n: int = 0):
@@ -35,7 +43,7 @@ def make_learner_mesh(n: int = 0):
     Sec. 9).  The learner count m must divide evenly over n."""
     if n == 0:
         n = len(jax.devices())
-    return jax.make_mesh((n,), ("learners",))
+    return _auto_mesh((n,), ("learners",))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

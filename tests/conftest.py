@@ -28,19 +28,15 @@ if os.environ.get("REPRO_DEBUG_NANS") == "1":
     jax.config.update("jax_debug_nans", True)
 
 # ---------------------------------------------------------------------------
-# THE backend-parity tolerance (ISSUE 7).
+# THE backend-parity tolerance, pinned in kernels/ops.py so
+# that chip_smoke.py holds the chip to the same pair.
 #
 # One pinned pair for every pallas-vs-reference comparison in the
-# suite — predictions, distances, divergences, fused rounds.  The
-# kernels accumulate in fp32 with a tile order that differs from the
-# jnp oracles, so values agree to a few ULP-amplified rounding steps;
-# rtol covers the large-magnitude RKHS distances, atol the near-zero
-# hinge margins.  Tests must not carry private tolerances for parity
-# checks: loosening THIS number is a reviewed decision, not a local
-# tweak.
+# suite — predictions, distances, divergences, fused rounds.  Tests
+# must not carry private tolerances for parity checks: loosening THIS
+# number is a reviewed decision, not a local tweak.
 # ---------------------------------------------------------------------------
-PARITY_RTOL = 1e-3
-PARITY_ATOL = 5e-3
+from repro.kernels.ops import PARITY_ATOL, PARITY_RTOL  # noqa: E402
 
 
 def assert_backend_parity(got, want, label: str = ""):

@@ -12,7 +12,7 @@ _SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.configs import get
     from repro.core.protocol import ProtocolConfig
@@ -21,7 +21,8 @@ _SCRIPT = textwrap.dedent("""
     from repro.optim import OptimizerConfig
 
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
     m = 4
 
     cfg = get("qwen2_5_3b").smoke()
@@ -72,6 +73,7 @@ _SCRIPT = textwrap.dedent("""
 def test_dynamic_protocol_on_host_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip the parent holds
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout + r.stderr

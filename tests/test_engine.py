@@ -313,3 +313,25 @@ def test_sweep_validates_inputs():
     with pytest.raises(ValueError):
         engine.sweep(lcfg, [ProtocolConfig(kind="dynamic")],
                      np.stack([X, X]), np.stack([Y, Y]))
+
+
+def test_assemble_sim_result_ignores_memory_order():
+    """The host-side learner sum gives one answer whether the (T, m)
+    series arrives row-major (the serving path stacks rows) or
+    column-major (the TPU returns the scan's outputs that way); numpy's
+    own summation order follows memory order."""
+    from repro.core.substrate import substrate_of
+
+    sub = substrate_of(_kernel_cfg())
+    rng = np.random.default_rng(0)
+    loss = rng.random((200, 32)).astype(np.float32)
+    err = (rng.random((200, 32)) < 0.3).astype(np.float32)
+    rest = (np.zeros(200, np.int64), np.zeros(200, np.float32),
+            np.zeros(200, bool), np.zeros(200, np.float32))
+    assert not np.array_equal(loss.sum(axis=1),
+                              np.asfortranarray(loss).sum(axis=1))
+    c = engine.assemble_sim_result(sub, False, loss, err, *rest)
+    f = engine.assemble_sim_result(sub, False, np.asfortranarray(loss),
+                                   np.asfortranarray(err), *rest)
+    np.testing.assert_array_equal(c.cumulative_loss, f.cumulative_loss)
+    np.testing.assert_array_equal(c.cumulative_errors, f.cumulative_errors)

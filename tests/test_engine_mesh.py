@@ -104,6 +104,7 @@ _SCRIPT = textwrap.dedent("""
 def test_sharded_engine_matches_single_device():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip the parent holds
     r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -117,14 +118,19 @@ def test_sharded_engine_matches_single_device():
 
 def test_learner_axes_resolution():
     import jax
+    from jax.sharding import AxisType
 
     from repro.core.engine import learner_axes_of
 
-    mesh = jax.make_mesh((1,), ("learners",))
+    def mesh_of(shape, axes):
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
+
+    mesh = mesh_of((1,), ("learners",))
     assert learner_axes_of(mesh) == ("learners",)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = mesh_of((1, 1), ("data", "model"))
     assert learner_axes_of(mesh) == ("data",)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = mesh_of((1,), ("model",))
     with pytest.raises(ValueError, match="learner axis"):
         learner_axes_of(mesh)
 

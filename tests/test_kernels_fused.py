@@ -15,6 +15,7 @@ Three contracts from ISSUE 7:
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -209,14 +210,81 @@ def test_autotune_cache_deterministic_off_tpu():
     autotune.clear_cache()
     try:
         calls = []
-        b1 = autotune.tuned_blocks("op", (300, 40), kind="k",
-                                   measure=lambda blk: calls.append(blk))
-        b2 = autotune.tuned_blocks("op", (300, 40), kind="k",
-                                   measure=lambda blk: calls.append(blk))
+        b1 = autotune.tuned_blocks(
+            "op", (300, 40), kind="k",
+            compile_candidate=lambda blk: calls.append(blk))
+        b2 = autotune.tuned_blocks(
+            "op", (300, 40), kind="k",
+            compile_candidate=lambda blk: calls.append(blk))
         assert b1 == b2 == (128, 128)
         assert calls == [], "no search may run off-TPU"
         key = autotune.TileKey("op", (300, 40), "float32", "k")
         assert autotune.cache_info()[key].source == "default"
+    finally:
+        autotune.clear_cache()
+
+
+def _fake_tpu_search(monkeypatch, refuse):
+    """Run the TPU search path on the CPU: candidates in ``refuse`` are
+    refused the way the TPU compiler refuses a tile."""
+    monkeypatch.setattr(autotune, "_on_tpu", lambda: True)
+    monkeypatch.setattr(autotune, "_SEARCH_ITERS", 1)
+
+    def compile_candidate(blocks):
+        if blocks in refuse:
+            raise ValueError(f"refused {blocks}")
+        return lambda: jnp.zeros(())
+
+    return compile_candidate
+
+
+def test_autotune_search_skips_refused_candidates(monkeypatch):
+    autotune.clear_cache()
+    try:
+        compile_candidate = _fake_tpu_search(monkeypatch, {(128,), (512,)})
+        blocks = autotune.tuned_blocks("op", (600,), kind="k",
+                                       compile_candidate=compile_candidate)
+        assert blocks == (256,)
+        key = autotune.TileKey("op", (600,), "float32", "k")
+        assert autotune.cache_info()[key].source == "search"
+    finally:
+        autotune.clear_cache()
+
+
+def test_autotune_search_raises_when_every_candidate_refused(monkeypatch):
+    autotune.clear_cache()
+    try:
+        compile_candidate = _fake_tpu_search(
+            monkeypatch, {(128,), (256,), (512,)})
+        with pytest.raises(RuntimeError, match="refused every tile"):
+            autotune.tuned_blocks("op", (600,), kind="k",
+                                  compile_candidate=compile_candidate)
+        assert autotune.cache_info() == {}
+    finally:
+        autotune.clear_cache()
+
+
+def test_autotune_never_searches_under_a_trace(monkeypatch):
+    """A traced operand means the op is being staged into an outer jit:
+    the resolver takes the defaults and launches nothing."""
+    autotune.clear_cache()
+    try:
+        calls = []
+        monkeypatch.setattr(autotune, "_on_tpu", lambda: True)
+
+        def compile_candidate(blocks):
+            calls.append(blocks)
+            return lambda: jnp.zeros(())
+
+        @jax.jit
+        def traced(x):
+            blocks = autotune.tuned_blocks(
+                "op", (600,), kind="k", compile_candidate=compile_candidate,
+                operands=(x,))
+            return x * blocks[0]
+
+        assert float(traced(jnp.ones(()))) == 128.0
+        assert calls == []
     finally:
         autotune.clear_cache()
 

@@ -437,6 +437,7 @@ def test_population_deterministic_across_processes():
     """)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip the parent holds
     env["PYTHONHASHSEED"] = "99"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
@@ -588,6 +589,7 @@ _MESH_SCRIPT = textwrap.dedent("""
 def test_masked_engine_matches_single_device_on_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip the parent holds
     r = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
                        capture_output=True, text=True, timeout=900)
     assert r.returncode == 0, r.stdout + r.stderr

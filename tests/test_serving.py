@@ -9,6 +9,7 @@ losses / errors and integer-exactly on the Sec. 3 byte ledger, for
 the floats a per-request ``predict_one`` would have produced
 (micro-batching is free, numerically).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -363,8 +364,12 @@ _MESH_SCRIPT = textwrap.dedent("""
 
 
 def test_mesh_routed_serving_matches_engine():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip the parent holds
     out = subprocess.run(
-        [sys.executable, "-c", _MESH_SCRIPT], capture_output=True, text=True)
+        [sys.executable, "-c", _MESH_SCRIPT], env=env, capture_output=True,
+        text=True, timeout=600)
     assert out.returncode == 0, out.stderr
     assert "MESH_SERVING_OK" in out.stdout
 
