@@ -14,7 +14,7 @@ against benchmarks/baselines/BENCH_kernels.json in CI):
 - ``kernels/serve_bucket/bucket_predict_hits_pallas`` — replaying a
   query-bearing stream through the serving engine with an ENGAGED
   pallas SV substrate routes bucketized predicts through the fused
-  ``ops.sv_predict`` kernel, observed via ``ops.LAUNCH_COUNTS``.
+  ``ops.sv_predict`` kernel, observed via ``ops.TRACE_COUNTS``.
 """
 from __future__ import annotations
 
@@ -94,11 +94,11 @@ def _serve_bucket_rows(quick: bool):
     Y = np.asarray(rng.choice([-1.0, 1.0], size=(T, m)), np.float32)
     sub = _sv_sub(budget, d, "pallas")
     pcfg = ProtocolConfig(kind="periodic", period=10)
-    before = ops.LAUNCH_COUNTS["sv_predict"]
+    before = ops.TRACE_COUNTS["sv_predict"]
     t0 = time.perf_counter()
     res = serve_stream(sub, pcfg, X, Y, queries_per_round=1.0)
     wall_us = (time.perf_counter() - t0) * 1e6
-    hits = ops.LAUNCH_COUNTS["sv_predict"] - before
+    hits = ops.TRACE_COUNTS["sv_predict"] - before
     # ledger parity with the scan engine is part of the claim: routing
     # predicts through the fused kernel must not perturb the protocol
     ref_res = core_engine.run(sub, pcfg, X, Y)

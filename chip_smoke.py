@@ -27,7 +27,7 @@ sync rounds integer-exact, losses bitwise.
 
 The script exits non-zero, and prints no result line, unless JAX's
 default backend is a TPU (there is no CPU or interpret fallback) and
-every check passes.  ``ops.LAUNCH_COUNTS`` proves that the fused kernels
+every check passes.  ``ops.TRACE_COUNTS`` proves that the fused kernels
 produced the numbers.  Times printed here are one smoke run's wall
 times, compilation included where it says so, not benchmark results.
 The last line of a passing run is one JSON object:
@@ -223,10 +223,10 @@ def phase_engine_sv(dep):
     finals = {}
     for kind, pcfg in dep["protos"].items():
         log(f"(a) engine, SV budget {BUDGET}, {kind}")
-        ops.reset_launch_counts()
+        ops.reset_trace_counts()
         r_p = run_twice(sv_p, pcfg, X, Y)
-        counts = dict(ops.LAUNCH_COUNTS)
-        log(f"  launch counts (pallas run): {counts}")
+        counts = dict(ops.TRACE_COUNTS)
+        log(f"  trace counts (pallas run): {counts}")
         check(counts.get("sv_predict", 0) > 0, "sv_predict launched")
         if kind == "dynamic":
             check(counts.get("quadform", 0) > 0, "quadform launched")
@@ -256,7 +256,7 @@ def phase_engine_sv(dep):
     models = {k: sv_p.models_of(c[0]) for k, c in finals.items()}
     ref = finals["periodic"][1]
     xq = jax.numpy.asarray(X[-1])
-    ops.reset_launch_counts()
+    ops.reset_trace_counts()
     got = {}
     for name, sub in (("pallas", sv_p), ("reference", sv_r)):
         predict, dist = jax.jit(sub.predict), jax.jit(sub.dist_to_ref)
@@ -264,8 +264,8 @@ def phase_engine_sv(dep):
             "sv_predict, dynamic run": predict(models["dynamic"], xq),
             "sv_predict, periodic run": predict(models["periodic"], xq),
             "dist_to_ref (quadform)": dist(models["dynamic"], ref)}
-    check(ops.LAUNCH_COUNTS["sv_predict"] > 0
-          and ops.LAUNCH_COUNTS["quadform"] > 0,
+    check(ops.TRACE_COUNTS["sv_predict"] > 0
+          and ops.TRACE_COUNTS["quadform"] > 0,
           "final-model checks ran sv_predict and quadform")
     for what, b in got["reference"].items():
         a, b = np.asarray(got["pallas"][what]), np.asarray(b)
@@ -286,10 +286,10 @@ def phase_engine_rff(dep):
     X, Y = dep["X"], dep["Y"]
     pcfg = dep["protos"]["dynamic"]
     log(f"(b) engine, RFF {RFF_FEATURES} features, dynamic")
-    ops.reset_launch_counts()
+    ops.reset_trace_counts()
     r_p = run_twice(dep["rff"]["pallas"], pcfg, X, Y)
-    counts = dict(ops.LAUNCH_COUNTS)
-    log(f"  launch counts (pallas run): {counts}")
+    counts = dict(ops.TRACE_COUNTS)
+    log(f"  trace counts (pallas run): {counts}")
     check(counts.get("rff_step", 0) > 0, "rff_step launched")
     check(bool(np.all(np.isfinite(r_p.cumulative_loss))), "losses finite")
     r_r, t = timed(engine.run, dep["rff"]["reference"], pcfg, X, Y)
@@ -316,16 +316,16 @@ def phase_serving(dep, seed: int):
     horizon = float(np.max(np.cumsum(
         SystemModel(SystemConfig(), M).draw_compute(SERVE_ROUNDS), axis=0)))
     offered = len(arrivals.times(horizon))
-    ops.reset_launch_counts()
+    ops.reset_trace_counts()
     res, t = timed(serve_stream, sub, pcfg, X, Y, arrivals=arrivals,
                    query_seed=seed, policy="continuous", slots=1,
                    predict_cost=PREDICT_COST, slo=4 * PREDICT_COST)
-    n_pred = ops.LAUNCH_COUNTS["sv_predict"]
+    n_pred = ops.TRACE_COUNTS["sv_predict"]
     log(f"  serve_stream: {t:.3f} s wall (compilation included); "
         f"{res.num_requests} requests, {res.rounds} rounds, "
         f"{res.launches} predict launches, buckets "
         f"{dict(sorted(res.bucket_counts.items()))}")
-    log(f"  launch counts: {dict(ops.LAUNCH_COUNTS)}")
+    log(f"  trace counts: {dict(ops.TRACE_COUNTS)}")
     check(res.num_requests == offered and res.num_shed == 0
           and bool(np.all(np.isfinite(res.latencies))),
           f"all {offered} offered predict requests answered")
@@ -356,11 +356,11 @@ def phase_mesh(dep):
     for name, sub in subs:
         for kind, pcfg in dep["protos"].items():
             log(f"{name} {kind}")
-            ops.reset_launch_counts()
+            ops.reset_trace_counts()
             r1, t1 = timed(engine.run, sub, pcfg, X, Y)
             r4, t4 = timed(engine.run, sub, pcfg, X, Y, mesh=mesh)
             log(f"  single device {t1:.3f} s, mesh {t4:.3f} s (compile "
-                f"included); launch counts {dict(ops.LAUNCH_COUNTS)}")
+                f"included); trace counts {dict(ops.TRACE_COUNTS)}")
             exact = (np.array_equal(r1.cumulative_bytes, r4.cumulative_bytes)
                      and np.array_equal(r1.sync_rounds, r4.sync_rounds))
             bitwise = (np.array_equal(r1.cumulative_loss, r4.cumulative_loss)

@@ -89,6 +89,9 @@ from .learners import LearnerConfig
 from .protocol import PROTOCOL_KIND_CODES, ProtocolConfig
 from .simulation import SimResult
 from .substrate import Substrate
+# probe imports only jax, so this holds whichever of repro.core and
+# repro.telemetry (whose monitor imports repro.core) is imported first
+from ..telemetry import probe
 
 Array = jnp.ndarray
 
@@ -253,15 +256,16 @@ def _make_step(sub: Substrate, kind: str, record_divergence: bool,
             x, y, t = xs
         pre_state = state
 
-        if sub.fused_scan_round:
-            # one fused round: predict + update share their featurize/
-            # Gram work (and under an engaged pallas backend run as a
-            # single kernel launch) — core/substrate.py round_stacked
-            state, losses, yhat = sub.round_stacked(state, (x, y))
-        else:
-            yhat = sub.predict(sub.models_of(state), x)
-            state, losses = sub.update(state, (x, y))
-        err = _err_terms(sub.loss, yhat, y)         # per-learner
+        with jax.named_scope(probe.SCOPE_PREDICT_UPDATE):
+            if sub.fused_scan_round:
+                # one fused round: predict + update share their featurize/
+                # Gram work (and under an engaged pallas backend run as a
+                # single kernel launch) — core/substrate.py round_stacked
+                state, losses, yhat = sub.round_stacked(state, (x, y))
+            else:
+                yhat = sub.predict(sub.models_of(state), x)
+                state, losses = sub.update(state, (x, y))
+            err = _err_terms(sub.loss, yhat, y)     # per-learner
         if masked:
             # inactive learners: no round happened — state stays as the
             # (possibly rejoin-adopted) pre-round state, observables
@@ -302,19 +306,20 @@ def _make_step(sub: Substrate, kind: str, record_divergence: bool,
                     violations = p & violations
                 return jnp.any(violations)
 
-            if sub.guarded_dist_check:
-                # the distance costs a Gram — only pay it on check
-                # rounds (lax.cond skips the untaken branch)
-                violated = lax.cond(check_now, check,
-                                    lambda _: jnp.zeros((), bool), None)
-            else:
-                violated = check_now & check(None)
-            if sharded:
-                # the one-bit violation all-reduce: the only
-                # unconditional cross-device traffic of the protocol
-                do_sync = lax.psum(violated.astype(jnp.int32), axis) > 0
-            else:
-                do_sync = violated
+            with jax.named_scope(probe.SCOPE_CHECK):
+                if sub.guarded_dist_check:
+                    # the distance costs a Gram — only pay it on check
+                    # rounds (lax.cond skips the untaken branch)
+                    violated = lax.cond(check_now, check,
+                                        lambda _: jnp.zeros((), bool), None)
+                else:
+                    violated = check_now & check(None)
+                if sharded:
+                    # the one-bit violation all-reduce: the only
+                    # unconditional cross-device traffic of the protocol
+                    do_sync = lax.psum(violated.astype(jnp.int32), axis) > 0
+                else:
+                    do_sync = violated
 
         if kind == "none":
             new_models, new_ref, new_ledger = models, reference, ledger
@@ -364,9 +369,10 @@ def _make_step(sub: Substrate, kind: str, record_divergence: bool,
                         jnp.zeros((), jnp.int32),
                         jnp.zeros((), jnp.float32))
 
-            new_models, new_ref, new_ledger, nbytes, eps = lax.cond(
-                do_sync, sync_branch, keep_branch,
-                (models, reference, ledger))
+            with jax.named_scope(probe.SCOPE_SYNC):
+                new_models, new_ref, new_ledger, nbytes, eps = lax.cond(
+                    do_sync, sync_branch, keep_branch,
+                    (models, reference, ledger))
 
         state = sub.with_models(state, new_models)
         if record_divergence or sub.free_divergence:
@@ -696,30 +702,34 @@ def run(
     all-True mask both produce the exact unmasked result — losses
     bitwise, bytes integer-exact (tests/test_population.py).
     """
-    sub = substrate_mod.substrate_of(
-        learner, sync_budget=sync_budget, compress_method=compress_method,
-        backend=backend)
-    if not isinstance(X, jax.Array):   # keep pre-sharded streams on device
-        X = np.asarray(X)
-    T, m, d = X.shape
-    sub.validate(T, m, d)
-    axes = _resolve_mesh(mesh, topology, m)
-    masked = participation is not None
-    if masked:
-        part = np.asarray(participation)
-        if part.shape != (T, m):
-            raise ValueError(
-                f"participation shape {part.shape} != (T, m) = {(T, m)}")
-        part = jnp.asarray(part.astype(bool))
-    fn = _jitted(sub, pcfg.kind, bool(record_divergence), False, False,
-                 topology, mesh, axes, masked)
-    if masked:
-        outs = fn(_params_of(pcfg), jnp.asarray(X), jnp.asarray(Y), part)
-    else:
-        outs = fn(_params_of(pcfg), jnp.asarray(X), jnp.asarray(Y))
-    loss, err, nbytes, div, flags, eps = (np.asarray(o) for o in outs)
-    return assemble_sim_result(sub, bool(record_divergence),
-                               loss, err, nbytes, div, flags, eps)
+    with jax.profiler.TraceAnnotation(probe.ENGINE_RUN):
+        sub = substrate_mod.substrate_of(
+            learner, sync_budget=sync_budget,
+            compress_method=compress_method, backend=backend)
+        if not isinstance(X, jax.Array):   # keep pre-sharded streams on device
+            X = np.asarray(X)
+        T, m, d = X.shape
+        sub.validate(T, m, d)
+        axes = _resolve_mesh(mesh, topology, m)
+        masked = participation is not None
+        if masked:
+            part = np.asarray(participation)
+            if part.shape != (T, m):
+                raise ValueError(
+                    f"participation shape {part.shape} != (T, m) = {(T, m)}")
+        fn = _jitted(sub, pcfg.kind, bool(record_divergence), False, False,
+                     topology, mesh, axes, masked)
+        with jax.profiler.TraceAnnotation(probe.ENGINE_UPLOAD):
+            args = (_params_of(pcfg), jnp.asarray(X), jnp.asarray(Y))
+            if masked:
+                args += (jnp.asarray(part.astype(bool)),)
+        with jax.profiler.TraceAnnotation(probe.ENGINE_DISPATCH):
+            outs = fn(*args)
+        with jax.profiler.TraceAnnotation(probe.ENGINE_COPY_BACK):
+            loss, err, nbytes, div, flags, eps = [np.asarray(o) for o in outs]
+        with jax.profiler.TraceAnnotation(probe.ENGINE_ASSEMBLE):
+            return assemble_sim_result(sub, bool(record_divergence),
+                                       loss, err, nbytes, div, flags, eps)
 
 
 @dataclasses.dataclass

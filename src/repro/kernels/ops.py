@@ -22,10 +22,14 @@ knobs.  Calling an op eagerly pays one dict lookup + one jit-cache hit
 per call; calling it inside an outer jit (the substrate under the scan
 engine) resolves everything at trace time and inlines the launcher.
 
-``LAUNCH_COUNTS`` ticks once per *traced* Pallas launch (per call when
-eager) — the path-proof used by the backend-parity tests and the
-serving ``bucket_predict_hits_pallas`` claim: parity says the numbers
-match, the counter says the fused kernel actually produced them.
+``TRACE_COUNTS`` ticks once per *trace* of a Pallas launch site (per
+call when eager; once per compile inside an outer jit, however many
+times the compiled program then launches the kernel) — the path-proof
+used by the backend-parity tests and the serving
+``bucket_predict_hits_pallas`` claim: parity says the numbers match,
+the counter says the fused kernel actually produced them.  Device
+launches are read from a profiler trace, not from here
+(``chipbench/trace.py``).
 """
 from __future__ import annotations
 
@@ -44,7 +48,7 @@ from .rff import rff_pallas
 _LANE = 128          # TPU lane width: last-dim alignment
 _MIN_PALLAS = 128    # below this, use the jnp reference
 
-LAUNCH_COUNTS: collections.Counter = collections.Counter()
+TRACE_COUNTS: collections.Counter = collections.Counter()
 
 # The backend-parity tolerance: one pinned pair for every pallas-vs-
 # reference comparison (tests/conftest.py, chip_smoke.py).  The kernels
@@ -67,8 +71,8 @@ def engages(*dims) -> bool:
     return max(int(d) for d in dims) >= _MIN_PALLAS
 
 
-def reset_launch_counts() -> None:
-    LAUNCH_COUNTS.clear()
+def reset_trace_counts() -> None:
+    TRACE_COUNTS.clear()
 
 
 def _on_tpu() -> bool:
@@ -223,7 +227,7 @@ def gram(X, Y, *, kind="gaussian", gamma=1.0, degree=3, coef0=1.0,
         block_m, block_n = _tuned("gram", (M, N), X.dtype,
                                   f"{kind}:d={X.shape[1]}", _gram_call,
                                   (X, Y), statics)
-    LAUNCH_COUNTS["gram"] += 1
+    TRACE_COUNTS["gram"] += 1
     return _gram_call(X, Y, **statics((block_m, block_n)))
 
 
@@ -243,7 +247,7 @@ def rff_features(X, W, b, *, num_features=None, block_m=None, block_d=None,
         block_m, block_d = _tuned("rff", (M, D), X.dtype,
                                   f"d={X.shape[1]}", _rff_call, (X, W, b),
                                   statics)
-    LAUNCH_COUNTS["rff"] += 1
+    TRACE_COUNTS["rff"] += 1
     return _rff_call(X, W, b, **statics((block_m, block_d)))
 
 
@@ -264,7 +268,7 @@ def quadform(X, Y, alpha, beta, *, kind="gaussian", gamma=1.0, degree=3,
         block_m, block_n = _tuned("quadform", (M, N), X.dtype,
                                   f"{kind}:d={X.shape[1]}", _quadform_call,
                                   (X, Y, alpha, beta), statics)
-    LAUNCH_COUNTS["quadform"] += 1
+    TRACE_COUNTS["quadform"] += 1
     return _quadform_call(X, Y, alpha, beta, **statics((block_m, block_n)))
 
 
@@ -306,7 +310,7 @@ def sv_predict(X, SV, A, *, kind="gaussian", gamma=1.0, degree=3,
     if block_n is None:
         (block_n,) = _tuned("sv_predict", (N,), SV.dtype, f"{kind}:d={d}",
                             _sv_predict_call, (X, SV, A), statics)
-    LAUNCH_COUNTS["sv_predict"] += 1
+    TRACE_COUNTS["sv_predict"] += 1
     return _sv_predict_call(X, SV, A, **statics((block_n,)))
 
 
@@ -339,7 +343,7 @@ def fused_primal_step(X, Yl, w, b, *, W=None, bias=None, scale=1.0,
         (block_m,) = _tuned(op, (B,), X.dtype,
                             f"d={X.shape[1]}:D={D}:{loss}",
                             _primal_step_call, args, statics)
-    LAUNCH_COUNTS[op] += 1
+    TRACE_COUNTS[op] += 1
     return _primal_step_call(*args, **statics((block_m,)))
 
 

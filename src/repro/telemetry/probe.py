@@ -1,13 +1,5 @@
-"""Compile counters and honest wall-clock probes (DESIGN.md Sec. 11).
-
-Two measurement hazards this module exists to close:
-
-- **Phantom speed.** JAX dispatch is asynchronous: timing ``fn(x)``
-  without blocking measures how fast Python can *enqueue* work, not
-  how fast the device computes it.  Every timing path here calls
-  ``jax.block_until_ready`` on the produced values inside both the
-  warmup and the timed region (``benchmarks/common.timeit`` delegates
-  to the same discipline).
+"""Real-clock probes (DESIGN.md Sec. 11): compile counters, and the
+names under which the engine shows up in a JAX profiler trace.
 
 - **Silent recompiles.** The repo's compile-cache contracts (frozen
   hashable substrates keying ``engine._jitted``, one executable per
@@ -20,6 +12,16 @@ Two measurement hazards this module exists to close:
   assertable property (tests/test_telemetry.py pins the engine's
   cache-keying contract with it).
 
+- **Where a round and an experiment spend their time.** The protocol
+  step (``engine._make_step``) runs its three phases under
+  ``jax.named_scope`` (``SCOPE_*``): the names reach the compiled
+  program only as HLO ``op_name`` metadata, so the instructions and
+  their floats are those of an unscoped step, and a device trace's
+  operations map to a phase through the optimized HLO.  ``engine.run``
+  opens ``jax.profiler.TraceAnnotation`` host spans (``ENGINE_*``) on
+  the clock the profiler aligns the device planes to; with no profiler
+  running an annotation records nothing.
+
 The jax.monitoring API registers listeners for the life of the
 process; this module installs ONE module-level listener lazily and
 dispatches to whatever counters are currently active, so counters nest
@@ -27,11 +29,29 @@ and never leak.
 """
 from __future__ import annotations
 
-import dataclasses
-import time
-from typing import Any, Callable, List, Optional
+from typing import List
 
 import jax
+
+#: ``jax.named_scope`` names of the protocol step's phases: predict and
+#: update with the error terms, the dynamic check (with, on a mesh, its
+#: violation psum) and the sync ``lax.cond`` (with its all_gather)
+SCOPE_PREDICT_UPDATE = "predict_update"
+SCOPE_CHECK = "check"
+SCOPE_SYNC = "sync"
+STEP_SCOPES = (SCOPE_PREDICT_UPDATE, SCOPE_CHECK, SCOPE_SYNC)
+
+#: host spans of ``engine.run``: the whole call, then its four phases in
+#: order — uploading the streams and parameters, dispatching the jitted
+#: program, copying the six outputs back (which waits on the device),
+#: and assembling the ``SimResult``
+ENGINE_RUN = "repro.engine.run"
+ENGINE_UPLOAD = "repro.engine.upload"
+ENGINE_DISPATCH = "repro.engine.dispatch"
+ENGINE_COPY_BACK = "repro.engine.copy_back"
+ENGINE_ASSEMBLE = "repro.engine.assemble"
+ENGINE_PHASES = (ENGINE_UPLOAD, ENGINE_DISPATCH, ENGINE_COPY_BACK,
+                 ENGINE_ASSEMBLE)
 
 #: The monitoring event jax fires once per actual XLA backend compile.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -85,88 +105,3 @@ class CompileCounter:
 
     def __exit__(self, *exc) -> None:
         _active_counters.remove(self)
-
-
-@dataclasses.dataclass
-class TimedStats:
-    """What :func:`time_fn` measured."""
-
-    us_per_call: float       # mean wall time per timed call, blocked
-    iters: int
-    compiles: int            # backend compiles during the TIMED loop
-    warmup_compiles: int     # backend compiles during warmup
-    compile_secs: float      # seconds spent compiling during warmup
-
-
-def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5,
-            ) -> TimedStats:
-    """Time ``fn(*args)``, blocking on its outputs every iteration.
-
-    Warmup runs absorb compilation (and report it:
-    ``warmup_compiles`` / ``compile_secs``); the timed loop then
-    measures steady state — if anything compiles *inside* the timed
-    loop, ``compiles`` is nonzero and the number is not a steady-state
-    number, which callers can assert against.
-    """
-    with CompileCounter() as cw:
-        for _ in range(max(warmup, 0)):
-            jax.block_until_ready(fn(*args))
-    with CompileCounter() as ct:
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            jax.block_until_ready(fn(*args))
-        wall = time.perf_counter() - t0
-    return TimedStats(
-        us_per_call=wall / iters * 1e6,
-        iters=iters,
-        compiles=ct.compiles,
-        warmup_compiles=cw.compiles,
-        compile_secs=cw.compile_secs,
-    )
-
-
-class Wallclock:
-    """Handle yielded by :func:`wallclock`; ``track`` registers device
-    values the elapsed time must wait for."""
-
-    def __init__(self) -> None:
-        self.seconds: float = 0.0
-        self.compiles: int = 0
-        self._tracked: List[Any] = []
-
-    def track(self, value):
-        """Register a (pytree of) device value(s); returns it."""
-        self._tracked.append(value)
-        return value
-
-
-class wallclock:
-    """Timing context that always blocks on tracked device values::
-
-        with wallclock() as w:
-            out = w.track(jitted_step(carry, xs))
-        w.seconds, w.compiles
-
-    On exit the context blocks on everything ``track``ed (async
-    dispatch cannot leak out of the measurement) and records backend
-    compiles observed inside the region.
-    """
-
-    def __init__(self) -> None:
-        self._w = Wallclock()
-        self._counter = CompileCounter()
-
-    def __enter__(self) -> Wallclock:
-        self._counter.__enter__()
-        self._t0 = time.perf_counter()
-        return self._w
-
-    def __exit__(self, *exc) -> Optional[bool]:
-        try:
-            if exc == (None, None, None):
-                jax.block_until_ready(self._w._tracked)
-        finally:
-            self._w.seconds = time.perf_counter() - self._t0
-            self._counter.__exit__(*exc)
-            self._w.compiles = self._counter.compiles
-        return None

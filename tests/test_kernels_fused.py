@@ -117,7 +117,7 @@ def test_below_min_pallas_is_reference_bitwise():
     b = jnp.asarray(rng.normal(size=(30,)), jnp.float32)
     Xs, SVs, As = _sv_args(rng, 3, 40, 9)
     sargs, skw = _step_args(rng, 5, 9, D=40)
-    before = dict(ops.LAUNCH_COUNTS)
+    before = dict(ops.TRACE_COUNTS)
     checks = [
         (ops.gram(X, Y, gamma=0.5), ref.gram_ref(X, Y, gamma=0.5)),
         (ops.quadform(X, Y, a, b, gamma=0.5),
@@ -130,7 +130,7 @@ def test_below_min_pallas_is_reference_bitwise():
     checks += list(zip(got_step, want_step))
     for got, want in checks:
         assert np.array_equal(np.asarray(got), np.asarray(want))
-    assert dict(ops.LAUNCH_COUNTS) == before, "fallback must not launch"
+    assert dict(ops.TRACE_COUNTS) == before, "fallback must not launch"
 
 
 def test_force_pallas_on_small_shapes_is_close():

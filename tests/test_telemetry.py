@@ -24,8 +24,6 @@ import json
 import os
 import sys
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -39,8 +37,7 @@ from repro.runtime import (AsyncProtocolConfig, SystemConfig,
                            run_async_simulation)
 from repro.serving import serve_stream
 from repro.telemetry import (CompileCounter, CriterionMonitor, Tracer,
-                             monitor_result, monitor_sweep, time_fn,
-                             unit_bytes_of, wallclock)
+                             monitor_result, monitor_sweep, unit_bytes_of)
 from repro.telemetry.trace import PID_NETWORK, PID_SERVING, TICKS_PER_UNIT
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -295,23 +292,6 @@ def test_engine_sweep_one_compile_per_substrate_kind_group():
                      dyn + [ProtocolConfig(kind="periodic", period=7)],
                      X2, Y2)
     assert c2.compiles == 1                   # exactly the new group
-
-
-def test_time_fn_blocks_and_reports_compiles():
-    @jax.jit
-    def f(v):
-        return v * 2.0 + 1.0
-
-    v = jnp.arange(37, dtype=jnp.float32)
-    s1 = time_fn(f, v, warmup=1, iters=3)
-    assert s1.warmup_compiles >= 1 and s1.compiles == 0
-    assert s1.us_per_call > 0 and s1.iters == 3
-    s2 = time_fn(f, v, warmup=1, iters=3)
-    assert s2.warmup_compiles == 0            # cache hit on re-measure
-
-    with wallclock() as w:
-        w.track(f(v))
-    assert w.seconds > 0 and w.compiles == 0
 
 
 # ---------------------------------------------------------------------------
