@@ -237,7 +237,10 @@ def average_stacked(stacked: SVModel) -> SVModel:
     concatenation of all slots with coefficients divided by m; slots
     that share an sv_id are *semantically* merged (they represent the
     same point mass in H, and downstream Gram algebra treats duplicated
-    rows exactly as a merged coefficient would).  The result has budget
+    rows exactly as a merged coefficient would).  The compression error
+    of the sync is evaluated over the merged ids
+    (``compression.merge_dropped``), so its Gram has one row per
+    distinct dropped point, not one per slot.  The result has budget
     m * tau.
     """
     m, tau, d = stacked.sv.shape

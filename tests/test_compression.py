@@ -79,3 +79,115 @@ def test_compressed_update_is_approximately_loss_proportional():
     gap = np.abs(np.asarray(rkhs.predict(spec, f, jnp.asarray(X)))
                  - np.asarray(rkhs.predict(spec, fc, jnp.asarray(X))))
     assert float(gap.max()) <= float(eps) + 1e-4
+
+
+# -- epsilon over the distinct dropped ids ----------------------------------
+
+GAUSS = KernelSpec(kind="gaussian", gamma=0.3)
+
+
+def _slots(sv, alpha, ids):
+    return SVModel(sv=jnp.asarray(np.asarray(sv, np.float32)),
+                   alpha=jnp.asarray(np.asarray(alpha, np.float32)),
+                   sv_id=jnp.asarray(np.asarray(ids, np.int32)))
+
+
+def _shared_plus_new(m=8, tau=64, shared=40, new=20, seed=0):
+    """The union of m learners after a sync: each holds the same
+    ``shared`` points (ids 0..shared-1) plus ``new`` points of its own,
+    averaged slot by slot as rkhs.average_stacked does."""
+    rng = np.random.default_rng(seed)
+    d = 8
+    sv = np.zeros((m, tau, d), np.float32)
+    alpha = np.zeros((m, tau), np.float32)
+    ids = -np.ones((m, tau), np.int32)
+    xs, a = rng.normal(size=(shared, d)), rng.normal(size=shared)
+    for i in range(m):
+        sv[i, :shared], alpha[i, :shared] = xs, a
+        ids[i, :shared] = np.arange(shared)
+        sv[i, shared:shared + new] = rng.normal(size=(new, d))
+        alpha[i, shared:shared + new] = 0.3 * rng.normal(size=new)
+        ids[i, shared:shared + new] = 1000 + new * i + np.arange(new)
+    return rkhs.average_stacked(_slots(sv, alpha, ids)), tau
+
+
+def _distinct(n, tau, seed, d=8, holes=()):
+    rng = np.random.default_rng(seed)
+    ids = np.arange(n)
+    alpha = rng.normal(size=n)
+    ids[list(holes)] = -1
+    alpha[list(holes)] = 0.0
+    return _slots(rng.normal(size=(n, d)), alpha, ids), tau
+
+
+CASES = {
+    # duplicated ids: u far below the slot count
+    "shared_plus_new": lambda: _shared_plus_new(),
+    # every id distinct: u = 1100 rows in (512, 512) tiles, 3 a side,
+    # the last partial
+    "all_distinct_tiles": lambda: _distinct(1200, 100, seed=1),
+    # one tile (n < 512), u = 293 not a multiple of the tile
+    "one_partial_tile": lambda: _distinct(300, 7, seed=2),
+    # empty (-1) slots among the dropped ones
+    "empty_slots": lambda: _distinct(96, 20, seed=3,
+                                     holes=(0, 5, 17, 40, 41, 95)),
+    # at most tau active slots: nothing dropped
+    "nothing_dropped": lambda: _distinct(64, 64, seed=4, holes=range(10)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_merged_epsilon_matches_dense_slot_formula(case):
+    """truncate's epsilon, evaluated over the distinct dropped ids,
+    equals the dense slot-by-slot beta^T K beta; its model is the
+    parent's keep-and-pack output bitwise; two calls agree bitwise."""
+    f, tau = CASES[case]()
+    fc, eps = jax.jit(compression.truncate, static_argnums=(0, 2))(
+        GAUSS, f, tau)
+    keep = compression._top_tau_mask(f, tau)
+    beta = jnp.where(rkhs.active_mask(f) & ~keep, f.alpha, 0.0)
+    dense = float(jnp.sqrt(rkhs.quadform(rkhs.gram(GAUSS, f.sv, f.sv),
+                                         beta, beta)))
+    if case == "nothing_dropped":
+        assert float(eps) == 0.0
+    else:
+        assert dense > 0.0
+        np.testing.assert_allclose(float(eps), dense, rtol=1e-5)
+    packed = compression._pack_to_budget(f, keep, tau)
+    for got, want in zip(fc, packed):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _, again = compression.truncate(GAUSS, f, tau)
+    _, again_jit = jax.jit(compression.truncate, static_argnums=(0, 2))(
+        GAUSS, f, tau)
+    assert np.asarray(again_jit).tobytes() == np.asarray(eps).tobytes()
+    np.testing.assert_allclose(float(again), float(eps), rtol=1e-6)
+
+
+@pytest.mark.parametrize("max_tiles", [2, 3])
+def test_merged_epsilon_picks_tile_loop_or_one_gram_by_u(monkeypatch,
+                                                         max_tiles):
+    """u = 1100 merged points need 3 tiles a side: allowed 3, the tile
+    loop runs; allowed 2, one (n, n) Gram over the merged points.  Both
+    give the dense slot formula."""
+    f, tau = CASES["all_distinct_tiles"]()
+    keep = compression._top_tau_mask(f, tau)
+    beta = compression._dropped_beta(f, keep)
+    drop = compression.merge_dropped(f, beta)
+    monkeypatch.setattr(compression, "_MAX_TILES", max_tiles)
+    got = compression._merged_norm_sq(GAUSS, f.sv, drop)
+    dense = rkhs.quadform(rkhs.gram(GAUSS, f.sv, f.sv), beta, beta)
+    np.testing.assert_allclose(float(got), float(dense), rtol=1e-5)
+
+
+@pytest.mark.parametrize("case,want", [
+    # 8 learners x (40 shared + 20 own) points, 64 kept: the 64 largest
+    # |alpha| slots hold 8 copies of each of the 8 largest shared
+    # points, so 32 shared and all 160 own points are dropped
+    ("shared_plus_new", 192),
+    ("all_distinct_tiles", 1100),
+    ("empty_slots", 70),
+    ("nothing_dropped", 0),
+])
+def test_distinct_dropped_counts_merged_points(case, want):
+    f, tau = CASES[case]()
+    assert int(compression.distinct_dropped(f, tau)) == want

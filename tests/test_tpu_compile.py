@@ -105,3 +105,27 @@ def test_gram_compiles(one_chip):
                               interpret=False, **KERNEL)
 
     _assert_kernel(_compile(fn, one_chip, (N, d), (N, d)))
+
+
+def test_sync_truncate_compiles_tile_loop_and_one_gram(one_chip):
+    """A sync's truncation of the m-learner average (m N slots) to N:
+    its compression error is evaluated over merged ids, in (512, 512)
+    tiles or, where more than 24 tiles a side are needed, as one Gram
+    over the merged points; both branches compile for the chip and the
+    whole program stays within a few MB of device memory."""
+    from repro.core import compression
+    from repro.core.rkhs import KernelSpec, SVModel
+
+    spec = KernelSpec(**KERNEL)
+
+    def fn(sv, alpha, ids):
+        return compression.truncate(spec, SVModel(sv, alpha, ids), N)
+
+    shapes = [((B * N, d), jnp.float32), ((B * N,), jnp.float32),
+              ((B * N,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"f32[{N},{N}]" in text
+    assert " conditional(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
