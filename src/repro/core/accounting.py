@@ -153,9 +153,10 @@ def allgather_bytes(shard_bytes: int, m: int) -> int:
 # accounting expressed over fixed-shape sorted id arrays so it can live
 # inside a jitted ``lax.scan`` (core/engine.py): sets become
 # ID_SENTINEL-padded sorted arrays, distinctness a neighbour
-# comparison, membership a searchsorted probe (rkhs.sorted_unique /
-# rkhs.count_members).  tests/test_engine.py proves the two ledgers
-# agree byte-for-byte on randomized sync sequences.
+# comparison, membership one stable sort of the cache with the queried
+# ids (rkhs.sorted_unique / rkhs.count_known).  tests/test_engine.py
+# proves the two ledgers agree byte-for-byte on randomized sync
+# sequences.
 
 
 class DeviceLedger(NamedTuple):
@@ -193,6 +194,10 @@ def device_sync_bytes_kernel(
       upload   |s_i| B_alpha + |s_i \\ K| B_x
       download |U| B_alpha + (|U| - |s_i|) B_x
 
+    Summed over i, the upload needs only sum_i |s_i ∩ K|: one count over
+    all rows at once, taken by a single stable sort of K with the rows
+    (``rkhs.count_known``), with no per-row search.
+
     ``mask`` (m,) bool restricts the synchronization to a participating
     cohort (DESIGN.md Sec. 15): non-participating learners neither
     upload nor download, contribute nothing to the union, and the new
@@ -224,8 +229,7 @@ def device_sync_bytes_kernel(
         stacked_ids = jnp.where(mask[:, None], stacked_ids, -1)
     uniq, n = jax.vmap(rkhs.sorted_unique)(stacked_ids)    # (m, tau), (m,)
     union, u = rkhs.sorted_unique(uniq)                    # (m*tau,), ()
-    in_known = jax.vmap(
-        lambda q: rkhs.count_members(q, ledger.known))(uniq)  # (m,)
+    in_known = rkhs.count_known(uniq, ledger.known)  # sum_i |s_i ∩ K|
     n_total = jnp.sum(n)
     downloaders = (jnp.sum(
         # reprolint: allow[ACC01] int32 cohort count; the worst >= 2**31 guard above covers it
@@ -233,7 +237,7 @@ def device_sync_bytes_kernel(
         else m)
     total = (
         n_total * bm.B_alpha
-        + jnp.sum(n - in_known) * bm.B_x
+        + (n_total - in_known) * bm.B_x
         + downloaders * u * bm.B_alpha
         + (downloaders * u - n_total) * bm.B_x
     )

@@ -257,7 +257,8 @@ def average_stacked(stacked: SVModel) -> SVModel:
 # as a sorted int32 array whose inactive tail is padded with ID_SENTINEL.
 # This is what lets the byte accounting of Sec. 3 run under jit
 # (DESIGN.md Sec. 7): sorted arrays make distinctness a neighbour
-# comparison and membership a searchsorted probe, both static-shape.
+# comparison and membership one sort-merge (``count_known``), both
+# static-shape.
 ID_SENTINEL = jnp.iinfo(jnp.int32).max
 
 
@@ -292,6 +293,27 @@ def count_members(queries: Array, sorted_ids: Array) -> Array:
     idx = jnp.clip(jnp.searchsorted(sorted_ids, queries), 0,
                    sorted_ids.shape[0] - 1)
     hit = (sorted_ids[idx] == queries) & (queries < ID_SENTINEL)
+    return jnp.sum(hit.astype(jnp.int32))
+
+
+def count_known(queries: Array, known: Array) -> Array:
+    """How many active entries of ``queries`` have an id in ``known``.
+
+    ``known`` is a sorted-unique id array (``sorted_unique``'s output);
+    ``queries`` has any shape and order and may repeat ids: each active
+    entry that is a member counts once.  One stable sort of known ∪
+    queries, known first, puts each known id before the queries equal
+    to it, so a query is a member iff the running maximum of the known
+    ids sorted so far equals it.  Unlike ``count_members`` there is no
+    search, so no data-dependent loop of gathers on the device.
+    """
+    q = queries.reshape(-1)
+    ids = jnp.concatenate([known, q])
+    is_known = jnp.arange(ids.shape[0]) < known.shape[0]
+    ids, is_known = jax.lax.sort((ids, is_known), num_keys=1,
+                                 is_stable=True)
+    last = jax.lax.cummax(jnp.where(is_known, ids, -1))
+    hit = ~is_known & (ids == last) & (ids >= 0) & (ids < ID_SENTINEL)
     return jnp.sum(hit.astype(jnp.int32))
 
 

@@ -6,6 +6,7 @@ legacy Python-loop driver's byte ledger *exactly* (cumulative_bytes
 identical, sync decisions identical) and its losses / errors /
 divergences to float32 tolerance.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -70,26 +71,104 @@ def _random_id_config(rng, m, tau, pool):
     return ids
 
 
-def _assert_ledgers_agree(seed, m=3, tau=7, n_syncs=6):
+def _random_syncs(seed, m=3, tau=7, n_syncs=6):
     rng = np.random.default_rng(seed)
+    pool = []
+    return [(_random_id_config(rng, m, tau, pool), None)
+            for _ in range(n_syncs)]
+
+
+def _edge_syncs(case, m=3, tau=7):
+    """(ids, mask) sequences at the edges of the device ledger's
+    membership count: what the cache holds, and what a row holds."""
+    rng = np.random.default_rng(0)
+    every = np.arange(m * tau, dtype=np.int32).reshape(m, tau)
+    if case == "empty_cache":      # first sync only: nothing is known
+        return [(_random_id_config(rng, m, tau, []), None)]
+    if case == "cache_holds_every_id":
+        # the first sync fills all m tau slots; later rows are subsets
+        sub = np.where(rng.random((m, tau)) < 0.6, every[::-1], -1)
+        return [(every, None), (sub, None), (every, None)]
+    if case == "ids_shared_by_every_row":
+        row = rng.permutation(40)[:tau].astype(np.int32)
+        same = np.broadcast_to(row, (m, tau)).copy()
+        half = same.copy()
+        half[:, ::2] = 100 + np.arange(m)[:, None]
+        return [(same, None), (same, None), (half, None), (same, None)]
+    if case == "rows_of_minus_one":
+        empty = np.full((m, tau), -1, np.int32)
+        some = every.copy()
+        some[1] = -1
+        return [(empty, None), (some, None), (empty, None), (some, None)]
+    if case == "ids_at_sentinel_minus_one":
+        top = int(rkhs.ID_SENTINEL) - 1
+        ids = np.full((m, tau), -1, np.int32)
+        ids[:, 0] = top
+        ids[0, 1], ids[1, 1] = top - 1, 0
+        more = ids.copy()
+        more[2, 2:4] = [top - 2, top - 1]
+        return [(ids, None), (more, None), (ids, None)]
+    if case == "mask_cohort":
+        pool = []
+        masks = [[True] * m, [False] * m, [True, False, True],
+                 [False, True, False], [True, True, False]]
+        return [(_random_id_config(rng, m, tau, pool), np.asarray(mk))
+                for mk in masks]
+    raise ValueError(case)
+
+
+def _assert_ledgers_agree(syncs, m=3, tau=7):
+    """Each (ids, mask) sync through the device ledger and through the
+    host set oracle (over the cohort rows where a mask is given)."""
     bm = ByteModel(dim=5)
     host = CommunicationLedger(bm)
     dev = accounting.device_ledger_init(m * tau)
-    pool = []
-    for t in range(n_syncs):
-        ids = _random_id_config(rng, m, tau, pool)
-        b_host = host.record_kernel_sync([ids[i] for i in range(m)], t)
+    for t, (ids, mask) in enumerate(syncs):
+        rows = [ids[i] for i in range(m) if mask is None or mask[i]]
+        b_host = host.record_kernel_sync(rows, t)
         b_dev, dev = accounting.device_sync_bytes_kernel(
-            bm, jnp.asarray(ids), dev)
+            bm, jnp.asarray(ids), dev,
+            mask=None if mask is None else jnp.asarray(mask))
         assert int(b_dev) == b_host, f"sync {t}: {int(b_dev)} != {b_host}"
     known_dev = np.asarray(dev.known)
     known_dev = set(known_dev[known_dev < int(rkhs.ID_SENTINEL)].tolist())
     assert known_dev == host.coordinator_known
 
 
-@pytest.mark.parametrize("seed", range(8))
+EDGE_CASES = ["empty_cache", "cache_holds_every_id",
+              "ids_shared_by_every_row", "rows_of_minus_one",
+              "ids_at_sentinel_minus_one", "mask_cohort"]
+
+
+@pytest.mark.parametrize("seed", [*range(8), *EDGE_CASES])
 def test_device_ledger_matches_host_ledger(seed):
-    _assert_ledgers_agree(seed)
+    """Randomized sync sequences (an int seed) and the named edge cases
+    agree byte-for-byte with the host ledger."""
+    _assert_ledgers_agree(_random_syncs(seed) if isinstance(seed, int)
+                          else _edge_syncs(seed))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_count_known_equals_per_row_count_members(seed):
+    """The one merge count over all rows is the sum of the per-row
+    binary-search counts it replaced, on random rows and caches."""
+    rng = np.random.default_rng(seed)
+    m, tau = int(rng.integers(1, 9)), int(rng.integers(1, 40))
+    pool = list(rng.integers(0, 200, 30))
+    ids = _random_id_config(rng, m, tau, pool)
+    uniq, _ = jax.vmap(rkhs.sorted_unique)(jnp.asarray(ids))
+    cache = np.where(rng.random(m * tau) < 0.5,
+                     rng.integers(0, 200, m * tau), -1).astype(np.int32)
+    known, _ = rkhs.sorted_unique(jnp.asarray(cache))
+    per_row = sum(int(rkhs.count_members(q, known)) for q in uniq)
+    assert int(rkhs.count_known(uniq, known)) == per_row
+    assert per_row == sum(
+        len(accounting.idset(r) & accounting.idset(cache)) for r in ids)
+    # the same distinct ids unsorted, with -1 for empty slots
+    raw = np.where(np.asarray(uniq) == int(rkhs.ID_SENTINEL), -1,
+                   np.asarray(uniq))
+    raw = rng.permuted(raw, axis=1)
+    assert int(rkhs.count_known(jnp.asarray(raw), known)) == per_row
 
 
 def test_device_ledger_empty_and_full():
@@ -128,7 +207,8 @@ def test_device_ledger_property():
     @settings(max_examples=15, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1))
     def inner(seed):
-        _assert_ledgers_agree(seed, m=4, tau=5, n_syncs=4)
+        _assert_ledgers_agree(_random_syncs(seed, m=4, tau=5, n_syncs=4),
+                              m=4, tau=5)
 
     inner()
 

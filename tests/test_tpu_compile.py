@@ -129,3 +129,26 @@ def test_sync_truncate_compiles_tile_loop_and_one_gram(one_chip):
     assert f"f32[{N},{N}]" in text
     assert " conditional(" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_sync_ledger_compiles_without_while_or_gather(one_chip):
+    """A sync's Sec. 3 byte ledger at m=32 learners of N slots against
+    a coordinator cache of m N: membership is one sort-merge, so the
+    program holds no search loop and no gather, and its temporaries
+    stay within a few MB of device memory."""
+    from repro.core import accounting
+
+    bm = accounting.ByteModel(dim=d)
+
+    def fn(ids, known):
+        return accounting.device_sync_bytes_kernel(
+            bm, ids, accounting.DeviceLedger(known))
+
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in [(B, N), (B * N,)]]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert " gather(" not in text
+    assert " sort(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
