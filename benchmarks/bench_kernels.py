@@ -120,11 +120,12 @@ def run(quick: bool = False):
     a = jnp.asarray(rng.normal(size=(M,)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(M,)), jnp.float32)
 
+    spec = KernelSpec("gaussian", gamma=0.5)
     rows = []
     g_ref = jax.jit(lambda X, Y: ref.gram_ref(X, Y, gamma=0.5))
     us = timeit(g_ref, X, Y)
     rows.append(Row("kernels/gram_jnp_oracle", us, f"M={M};d={d}"))
-    us = timeit(lambda: ops.gram(X, Y, gamma=0.5, force_pallas=True))
+    us = timeit(lambda: ops.gram(spec, X, Y))
     rows.append(Row("kernels/gram_pallas_interpret", us,
                     "validated=allclose;mode=interpret(CPU)"))
 
@@ -134,8 +135,7 @@ def run(quick: bool = False):
     hbm_fused = 2 * M * d * 4
     rows.append(Row("kernels/quadform_jnp_oracle", us,
                     f"hbm_gram_bytes={hbm_naive}"))
-    us = timeit(lambda: ops.quadform(X, Y, a, b, gamma=0.5,
-                                    force_pallas=True))
+    us = timeit(lambda: ops.quadform(spec, X, Y, a, b))
     rows.append(Row("kernels/quadform_pallas_interpret", us,
                     f"hbm_stream_bytes={hbm_fused};"
                     f"traffic_saving={hbm_naive / hbm_fused:.0f}x"))
@@ -145,7 +145,7 @@ def run(quick: bool = False):
     r_ref = jax.jit(lambda X: ref.rff_ref(X, W, bias))
     us = timeit(r_ref, X)
     rows.append(Row("kernels/rff_jnp_oracle", us, f"D={M}"))
-    us = timeit(lambda: ops.rff_features(X, W, bias, force_pallas=True))
+    us = timeit(lambda: ops.rff_features(X, W, bias))
     rows.append(Row("kernels/rff_pallas_interpret", us,
                     "fused=proj+bias+cos"))
 
@@ -157,8 +157,7 @@ def run(quick: bool = False):
     sv_ref = jax.jit(lambda X, S, A: ref.sv_predict_ref(X, S, A, gamma=0.5))
     us = timeit(sv_ref, Xs, SVs, As)
     rows.append(Row("kernels/sv_predict_jnp_oracle", us, f"B={B};N={N}"))
-    us = timeit(lambda: ops.sv_predict(Xs, SVs, As, gamma=0.5,
-                                       force_pallas=True))
+    us = timeit(lambda: ops.sv_predict(spec, Xs, SVs, As))
     rows.append(Row("kernels/sv_predict_pallas_interpret", us,
                     "fused=gram+mask+reduce;row_bits=batch_invariant"))
 
@@ -177,7 +176,7 @@ def run(quick: bool = False):
     rows.append(Row("kernels/rff_step_jnp_oracle", us, f"B={M};D={D}"))
     us = timeit(lambda: ops.fused_primal_step(
         Xp, Yp, w, bb, W=Wp, bias=bp, scale=scale, loss="hinge",
-        eta=0.5, lam=0.01, force_pallas=True))
+        eta=0.5, lam=0.01))
     rows.append(Row("kernels/rff_step_pallas_interpret", us,
                     "fused=featurize+dot+lossgrad+update"))
 

@@ -27,13 +27,16 @@ the pure-jnp definitions in core/rkhs.py and core/rff.py (the semantic
 oracles); ``backend="pallas"`` routes ``predict`` / ``predict_batch`` /
 ``dist_to_ref`` / ``divergence`` and the fused scan round through the
 fused TPU kernels ``kernels.ops.sv_predict`` / ``fused_primal_step`` /
-``quadform`` / ``rff_features`` (interpret mode validates them on
-CPU).  The dispatch is *engage-aware* (``kernels.ops.engages``): below
-the Pallas launch threshold the pallas backend runs the exact
-reference expressions, so small-model pallas runs are bit-identical to
-``backend="reference"`` — which is what makes the Def. 1 byte ledger
-backend-independent by construction (tools/substrate_matrix.py pins
-it across the full substrate x protocol x driver matrix).
+``rkhs_dist_sq`` / ``rff_features`` (interpret mode validates them on
+CPU).  This module is the one place that decides whether a kernel
+runs: ``Substrate._pallas(*dims)`` holds when the backend is pallas and
+a launch over those extents engages (``kernels.ops.engages``); the ops
+themselves only pad, launch and crop.  Below the Pallas launch
+threshold the pallas backend runs the exact reference expressions, so
+small-model pallas runs are bit-identical to ``backend="reference"`` —
+which is what makes the Def. 1 byte ledger backend-independent by
+construction (tools/substrate_matrix.py pins it across the full
+substrate x protocol x driver matrix).
 
 Two faces, one contract
 -----------------------
@@ -103,6 +106,13 @@ class Substrate:
     has_eps: bool = False
     free_divergence: bool = True
     guarded_dist_check: bool = False
+
+    def _pallas(self, *dims) -> bool:
+        """The one backend decision: the pallas backend AND a Pallas
+        launch over these extents engages (``kernels.ops.engages``).
+        Otherwise the reference expressions run verbatim, bit-identical
+        to backend="reference" (module docstring)."""
+        return self.backend == "pallas" and _kops().engages(*dims)
 
     # -- scan face ----------------------------------------------------------
 
@@ -384,30 +394,23 @@ class SVSubstrate(Substrate):
     def with_models(self, state, models):
         return state._replace(model=models)
 
-    def _engaged(self) -> bool:
-        """Pallas backend AND the SV budget reaches the launch
-        threshold.  Below it the reference expressions run verbatim —
-        bit-identical to backend="reference" (module docstring)."""
-        return self.backend == "pallas" and _kops().engages(self.lcfg.budget)
-
     def predict(self, models: SVModel, x: Array) -> Array:
-        if self._engaged():
+        if self._pallas(self.lcfg.budget):
             a = jnp.where(rkhs.active_mask(models), models.alpha, 0.0)
-            return _kops().sv_predict_spec(self.lcfg.kernel, x, models.sv, a)
+            return _kops().sv_predict(self.lcfg.kernel, x, models.sv, a)
         return jax.vmap(lambda f, xi: self.predict_one(f, xi))(models, x)
 
     def predict_batch(self, models: SVModel, lids: Array, Xb: Array) -> Array:
         # the serving bucket path: one fused sv_predict launch answers
         # the whole bucket.  Row floats still match predict_one —
-        # ops.sv_predict's blocks and engagement never depend on the
-        # batch size (kernels/ops.py), and each row is its own grid
-        # cell — so the serving bit-exactness contract holds on the
-        # fused path too (tests/test_backend_parity.py pins it).
-        if self._engaged():
+        # the engage decision and ops.sv_predict's tiles never depend
+        # on the batch size, and each row is its own grid cell — so the
+        # serving bit-exactness contract holds on the fused path too
+        # (tests/test_backend_parity.py pins it).
+        if self._pallas(self.lcfg.budget):
             picked = jax.tree.map(lambda v: v[lids], models)
             a = jnp.where(rkhs.active_mask(picked), picked.alpha, 0.0)
-            return _kops().sv_predict_spec(self.lcfg.kernel, Xb,
-                                           picked.sv, a)
+            return _kops().sv_predict(self.lcfg.kernel, Xb, picked.sv, a)
         return super().predict_batch(models, lids, Xb)
 
     def update(self, state, example):
@@ -487,13 +490,12 @@ class SVSubstrate(Substrate):
         # engage-gated like every pallas branch: the dynamic protocol's
         # sync decisions feed the byte ledger, so the non-engaged
         # pallas path must be the reference expression verbatim
-        if self.backend == "pallas" and _kops().engages(
-                self.lcfg.budget, self.sync_budget):
+        if self._pallas(self.lcfg.budget, self.sync_budget):
             return jax.vmap(lambda f: self.dist_one(f, ref))(models)
         return rkhs.stacked_dist_to(self.lcfg.kernel, models, ref)
 
     def divergence(self, models: SVModel) -> Array:
-        if self._engaged():
+        if self._pallas(self.lcfg.budget):
             fbar = rkhs.average_stacked(models)
             return jnp.mean(self.dist_to_ref(models, fbar))
         return rkhs.divergence_stacked(self.lcfg.kernel, models)
@@ -526,19 +528,18 @@ class SVSubstrate(Substrate):
 
     def predict_one(self, model: SVModel, x: Array) -> Array:
         spec = self.lcfg.kernel
-        if self._engaged():
+        if self._pallas(self.lcfg.budget):
             a = jnp.where(rkhs.active_mask(model), model.alpha, 0.0)
-            return _kops().sv_predict_spec(
+            return _kops().sv_predict(
                 spec, x[None], model.sv[None], a[None])[0]
         return rkhs.predict(spec, model, x[None])[0]
 
     def dist_one(self, model: SVModel, ref: SVModel) -> Array:
         spec = self.lcfg.kernel
-        if self.backend == "pallas" and _kops().engages(
-                model.sv.shape[0], ref.sv.shape[0]):
+        if self._pallas(model.sv.shape[0], ref.sv.shape[0]):
             af = jnp.where(rkhs.active_mask(model), model.alpha, 0.0)
             ag = jnp.where(rkhs.active_mask(ref), ref.alpha, 0.0)
-            return _kops().rkhs_dist_sq_spec(spec, model.sv, ref.sv, af, ag)
+            return _kops().rkhs_dist_sq(spec, model.sv, ref.sv, af, ag)
         return rkhs.dist_sq(spec, model, ref)
 
     def init_reference(self):
@@ -794,7 +795,7 @@ class LinearSubstrate(_PrimalSubstrate):
 
     lcfg: LearnerConfig = dataclasses.field(
         default_factory=lambda: LearnerConfig(algo="linear_sgd"))
-    backend: str = "reference"    # accepted for uniformity; no kernel algebra
+    backend: str = "reference"    # pallas: linear_sgd rounds as one fused step
 
     def __post_init__(self):
         if self.lcfg.is_kernel:
@@ -837,8 +838,8 @@ class LinearSubstrate(_PrimalSubstrate):
 
     def round_stacked(self, state, example):
         x, y = example
-        if (self.backend == "pallas" and self.lcfg.algo == "linear_sgd"
-                and _kops().engages(x.shape[0], self.lcfg.dim)):
+        if (self.lcfg.algo == "linear_sgd"
+                and self._pallas(x.shape[0], self.lcfg.dim)):
             w_new, b_new, ell, yhat = _kops().fused_primal_step(
                 x, y, state.w, state.b, loss=self.loss,
                 eta=self.lcfg.eta, lam=self.lcfg.lam)
@@ -915,8 +916,7 @@ class RFFSubstrate(_PrimalSubstrate):
         below the Pallas threshold the pallas backend featurizes with
         the reference map, bit-identical to backend="reference"."""
         W, b = _rff_consts(self.spec)
-        if self.backend == "pallas" and _kops().engages(
-                X2d.shape[0], self.spec.num_features):
+        if self._pallas(X2d.shape[0], self.spec.num_features):
             return _kops().rff_features(X2d, jnp.asarray(W), jnp.asarray(b))
         return rff.featurize(self.spec, jnp.asarray(W), jnp.asarray(b), X2d)
 
@@ -959,8 +959,7 @@ class RFFSubstrate(_PrimalSubstrate):
 
     def round_stacked(self, state, example):
         x, y = example
-        if self.backend == "pallas" and _kops().engages(
-                x.shape[0], self.spec.num_features):
+        if self._pallas(x.shape[0], self.spec.num_features):
             W, b = _rff_consts(self.spec)
             w_new, b_new, ell, yhat = _kops().fused_primal_step(
                 x, y, state.w, state.b,

@@ -1,9 +1,9 @@
 """Pallas TPU kernels for the paper compute hot-spots.
 
-``ops`` is the public face (padding, autotuned blocks, interpret-mode
-selection, tiny-shape fallback); ``autotune`` owns block-size choice;
-``fused`` holds the per-round fused kernels; ``ref`` the jnp oracles.
+``ops`` is the public face (padding, 128-wide tiles, interpret-mode
+selection); ``fused`` holds the per-round fused kernels; ``ref`` the
+jnp oracles.
 """
-from . import autotune, fused, ops, ref
+from . import fused, ops, ref
 
-__all__ = ["autotune", "fused", "ops", "ref"]
+__all__ = ["fused", "ops", "ref"]

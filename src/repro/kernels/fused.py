@@ -26,8 +26,7 @@ predict_batch bit-exactness contract (core/substrate.py) extends to
 the fused path by construction.
 
 Inputs arrive pre-padded to block multiples (ops.py pads and crops,
-exactly like the seed-era kernels); block sizes come from
-kernels/autotune.py.
+exactly like the seed-era kernels, and launches with 128-wide tiles).
 """
 from __future__ import annotations
 

@@ -33,7 +33,7 @@ from repro.core.learners import LearnerConfig
 from repro.core.protocol import ProtocolConfig
 from repro.core.rff import RFFSpec
 from repro.core.rkhs import KernelSpec, SVModel
-from repro.core.substrate import RFFSubstrate, SVSubstrate
+from repro.core.substrate import LinearSubstrate, RFFSubstrate, SVSubstrate
 from repro.kernels import ops
 
 try:
@@ -132,6 +132,23 @@ class TestSVParity:
             assert np.array_equal(np.asarray(got), np.asarray(want))
             assert float(got_d) == float(want_d)
 
+    def test_dist_one_straddle_picks_per_term(self):
+        """A learner budget above the threshold beside a sync budget
+        below it: dist_one engages, and rkhs_dist_sq launches the fused
+        quadform for exactly the two terms whose extents engage
+        (<f, f> at 129 x 129 and <f, r> at 129 x 64); <r, r> at 64 x 64
+        runs the reference quadratic form."""
+        lcfg = LearnerConfig(algo="kernel_sgd", budget=129, dim=7,
+                             kernel=KernelSpec(kind="gaussian", gamma=0.4))
+        ref_sub = SVSubstrate(lcfg=lcfg, sync_budget=64)
+        pal_sub = dataclasses.replace(ref_sub, backend="pallas")
+        model, ref_model = _one_model(11, 129, 7), _one_model(12, 64, 7)
+        before = ops.TRACE_COUNTS["quadform"]
+        got = pal_sub.dist_one(model, ref_model)
+        assert ops.TRACE_COUNTS["quadform"] == before + 2
+        assert_backend_parity(got, ref_sub.dist_one(model, ref_model),
+                              "straddled dist_one")
+
     @pytest.mark.parametrize("budget", [1, 129])
     def test_round_stacked(self, budget):
         ref_sub, pal_sub = _parity_pair(budget, 7, "gaussian")
@@ -222,9 +239,17 @@ class TestEngineParity:
         return X, Y
 
     @pytest.mark.parametrize("kind", ["periodic", "dynamic"])
-    def test_small_sv_bitwise(self, kind):
+    @pytest.mark.parametrize("learner", ["sv", "rff32", "linear"])
+    def test_small_sv_bitwise(self, learner, kind):
+        """Below the threshold the substrate alone decides to run the
+        reference expressions, so the pallas engine is bitwise the
+        reference engine for every substrate."""
         X, Y = self._stream()
-        sub = _sv_sub(32, 8)
+        sub = {"sv": lambda: _sv_sub(32, 8),
+               "rff32": lambda: RFFSubstrate(
+                   spec=RFFSpec(dim=8, num_features=32, seed=0)),
+               "linear": lambda: LinearSubstrate(
+                   lcfg=LearnerConfig(algo="linear_sgd", dim=8))}[learner]()
         pcfg = ProtocolConfig(kind=kind, period=10, delta=1.0, mini_batch=5)
         r_ref = engine.run(sub, pcfg, X, Y)
         r_pal = engine.run(dataclasses.replace(sub, backend="pallas"),

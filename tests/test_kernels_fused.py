@@ -1,17 +1,14 @@
-"""Fused kernels, fallback boundaries, and the block-size autotuner.
+"""Fused kernels, small-shape launches, and compile reuse.
 
-Three contracts from ISSUE 7:
-
-- fallback boundary: shapes below ``ops._MIN_PALLAS`` take the jnp
-  reference path bit-for-bit (and never launch); ``force_pallas=True``
-  on the same shapes still matches within the pinned parity tolerance;
-  ``_pad_to`` cropping is exact at n = mult +/- 1 for every kernel
-  kind;
+- the engage threshold (``ops.engages``) is the one the substrate
+  dispatches on; the ops themselves launch at any shape and match
+  their oracles within the pinned parity tolerance; ``_pad_to``
+  cropping is exact at n = mult +/- 1 for every kernel kind;
 - fused kernels equal their oracles (kernels/ref.py) for all kernel
   kinds and both losses;
-- the autotuner resolves deterministically off-TPU and value-equal
-  configs reuse tuned blocks with ZERO new XLA compiles
-  (telemetry.probe.CompileCounter) — the recompile-regression gate.
+- value-equal calls and configs reuse their executables with ZERO new
+  XLA compiles (telemetry.probe.CompileCounter) — the
+  recompile-regression gate.
 """
 import dataclasses
 
@@ -27,10 +24,11 @@ from repro.core.learners import LearnerConfig
 from repro.core.protocol import ProtocolConfig
 from repro.core.rkhs import KernelSpec
 from repro.core.substrate import SVSubstrate
-from repro.kernels import autotune, ops, ref
+from repro.kernels import ops, ref
 from repro.telemetry.probe import CompileCounter
 
 KINDS = ["gaussian", "linear", "poly"]
+SPEC = KernelSpec("gaussian", gamma=0.5)
 
 
 def _rng(seed=0):
@@ -69,7 +67,7 @@ def _step_args(rng, B, d, D=None):
 def test_sv_predict_matches_oracle(kind, N):
     X, SV, A = _sv_args(_rng(1), 4, N, 9)
     want = ref.sv_predict_ref(X, SV, A, kind=kind, gamma=0.5)
-    got = ops.sv_predict(X, SV, A, kind=kind, gamma=0.5, force_pallas=True)
+    got = ops.sv_predict(KernelSpec(kind, gamma=0.5), X, SV, A)
     assert got.shape == (4,)
     assert_backend_parity(got, want, f"sv_predict {kind} N={N}")
 
@@ -79,8 +77,7 @@ def test_sv_predict_matches_oracle(kind, N):
 def test_fused_rff_step_matches_oracle(loss, B):
     args, kw = _step_args(_rng(2), B, 9, D=140)
     want = ref.primal_step_ref(*args, loss=loss, eta=0.3, lam=0.01, **kw)
-    got = ops.fused_primal_step(*args, loss=loss, eta=0.3, lam=0.01,
-                                force_pallas=True, **kw)
+    got = ops.fused_primal_step(*args, loss=loss, eta=0.3, lam=0.01, **kw)
     for g, w, name in zip(got, want, ["w", "b", "ell", "yhat"]):
         assert_backend_parity(g, w, f"rff_step/{name} {loss} B={B}")
 
@@ -89,14 +86,13 @@ def test_fused_rff_step_matches_oracle(loss, B):
 def test_fused_linear_step_matches_oracle(B):
     args, _ = _step_args(_rng(3), B, 9)
     want = ref.primal_step_ref(*args, loss="hinge", eta=0.3, lam=0.01)
-    got = ops.fused_primal_step(*args, loss="hinge", eta=0.3, lam=0.01,
-                                force_pallas=True)
+    got = ops.fused_primal_step(*args, loss="hinge", eta=0.3, lam=0.01)
     for g, w, name in zip(got, want, ["w", "b", "ell", "yhat"]):
         assert_backend_parity(g, w, f"linear_step/{name} B={B}")
 
 
 # ---------------------------------------------------------------------------
-# Fallback boundary
+# Engage threshold and small-shape launches
 # ---------------------------------------------------------------------------
 
 
@@ -107,44 +103,44 @@ def test_engages_threshold():
     assert ops.engages(2, 128)
 
 
-def test_below_min_pallas_is_reference_bitwise():
-    """Sub-threshold calls return the jnp oracle's exact floats and
-    never count a launch."""
-    rng = _rng(4)
+def _small_launches():
+    """One small-shape call of each public op that launches a kernel:
+    {op name in TRACE_COUNTS: thunk returning (got, want)}."""
+    rng = _rng(5)
     X = jnp.asarray(rng.normal(size=(40, 9)), jnp.float32)
     Y = jnp.asarray(rng.normal(size=(30, 9)), jnp.float32)
     a = jnp.asarray(rng.normal(size=(40,)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(30,)), jnp.float32)
+    W = jnp.asarray(rng.normal(size=(30, 9)), jnp.float32)
+    bias = jnp.asarray(rng.uniform(0, 2 * np.pi, size=(30,)), jnp.float32)
     Xs, SVs, As = _sv_args(rng, 3, 40, 9)
     sargs, skw = _step_args(rng, 5, 9, D=40)
-    before = dict(ops.TRACE_COUNTS)
-    checks = [
-        (ops.gram(X, Y, gamma=0.5), ref.gram_ref(X, Y, gamma=0.5)),
-        (ops.quadform(X, Y, a, b, gamma=0.5),
-         ref.quadform_ref(X, Y, a, b, gamma=0.5)),
-        (ops.sv_predict(Xs, SVs, As, gamma=0.5),
-         ref.sv_predict_ref(Xs, SVs, As, gamma=0.5)),
-    ]
-    got_step = ops.fused_primal_step(*sargs, loss="hinge", **skw)
-    want_step = ref.primal_step_ref(*sargs, loss="hinge", **skw)
-    checks += list(zip(got_step, want_step))
-    for got, want in checks:
-        assert np.array_equal(np.asarray(got), np.asarray(want))
-    assert dict(ops.TRACE_COUNTS) == before, "fallback must not launch"
+    return {
+        "gram": lambda: (ops.gram(SPEC, X, Y), ref.gram_ref(X, Y, gamma=0.5)),
+        "quadform": lambda: (ops.quadform(SPEC, X, Y, a, b),
+                             ref.quadform_ref(X, Y, a, b, gamma=0.5)),
+        "rff": lambda: (ops.rff_features(X, W, bias),
+                        ref.rff_ref(X, W, bias)),
+        "sv_predict": lambda: (ops.sv_predict(SPEC, Xs, SVs, As),
+                               ref.sv_predict_ref(Xs, SVs, As, gamma=0.5)),
+        "rff_step": lambda: (ops.fused_primal_step(*sargs, loss="hinge",
+                                                   **skw),
+                             ref.primal_step_ref(*sargs, loss="hinge",
+                                                 **skw)),
+    }
 
 
-def test_force_pallas_on_small_shapes_is_close():
-    rng = _rng(5)
-    Xs, SVs, As = _sv_args(rng, 3, 40, 9)
-    assert_backend_parity(
-        ops.sv_predict(Xs, SVs, As, gamma=0.5, force_pallas=True),
-        ref.sv_predict_ref(Xs, SVs, As, gamma=0.5), "forced small sv")
-    sargs, skw = _step_args(rng, 5, 9, D=40)
-    got = ops.fused_primal_step(*sargs, loss="hinge", force_pallas=True,
-                                **skw)
-    want = ref.primal_step_ref(*sargs, loss="hinge", **skw)
-    for g, w in zip(got, want):
-        assert_backend_parity(g, w, "forced small step")
+@pytest.mark.parametrize("op", ["gram", "quadform", "rff", "sv_predict",
+                                "rff_step"])
+def test_ops_launch_at_small_shapes(op):
+    """An op launches its kernel at any shape: the engage decision is
+    the caller's (core.substrate), so below the threshold the op still
+    pads, launches and crops, within the pinned parity tolerance."""
+    before = ops.TRACE_COUNTS[op]
+    got, want = _small_launches()[op]()
+    assert ops.TRACE_COUNTS[op] == before + 1, f"{op} did not launch"
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert_backend_parity(g, w, f"small {op}")
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -157,13 +153,13 @@ def test_pad_crop_exact_every_kind(kind, n):
     Y = jnp.asarray(rng.normal(size=(n, 9)), jnp.float32)
     a = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
-    K = ops.gram(X, Y, kind=kind, gamma=0.5, force_pallas=True)
+    K = ops.gram(KernelSpec(kind, gamma=0.5), X, Y)
     assert K.shape == (n, n)
     np.testing.assert_allclose(
         np.asarray(K),
         np.asarray(ref.gram_ref(X, Y, kind=kind, gamma=0.5)),
         rtol=2e-5, atol=2e-5)
-    q = ops.quadform(X, Y, a, b, kind=kind, gamma=0.5, force_pallas=True)
+    q = ops.quadform(KernelSpec(kind, gamma=0.5), X, Y, a, b)
     assert q.shape == ()
     assert_backend_parity(q, ref.quadform_ref(X, Y, a, b, kind=kind,
                                               gamma=0.5), f"qf {kind} {n}")
@@ -175,134 +171,22 @@ def test_pad_crop_exact_rff_and_fused(n):
     X = jnp.asarray(rng.normal(size=(n, 9)), jnp.float32)
     W = jnp.asarray(rng.normal(size=(n, 9)), jnp.float32)
     bias = jnp.asarray(rng.uniform(0, 2 * np.pi, size=(n,)), jnp.float32)
-    Z = ops.rff_features(X, W, bias, force_pallas=True)
+    Z = ops.rff_features(X, W, bias)
     assert Z.shape == (n, n)
     np.testing.assert_allclose(
         np.asarray(Z), np.asarray(ref.rff_ref(X, W, bias)),
         rtol=2e-5, atol=2e-5)
     Xs, SVs, As = _sv_args(rng, 3, n, 9)
-    got = ops.sv_predict(Xs, SVs, As, gamma=0.5, force_pallas=True)
+    got = ops.sv_predict(SPEC, Xs, SVs, As)
     assert got.shape == (3,)
     assert_backend_parity(got, ref.sv_predict_ref(Xs, SVs, As, gamma=0.5),
                           f"sv crop {n}")
     sargs, skw = _step_args(rng, n, 9, D=n)
-    got = ops.fused_primal_step(*sargs, loss="hinge", force_pallas=True,
-                                **skw)
+    got = ops.fused_primal_step(*sargs, loss="hinge", **skw)
     want = ref.primal_step_ref(*sargs, loss="hinge", **skw)
     assert got[0].shape == (n, n) and got[1].shape == (n,)
     for g, w in zip(got, want):
         assert_backend_parity(g, w, f"step crop {n}")
-
-
-# ---------------------------------------------------------------------------
-# Autotuner
-# ---------------------------------------------------------------------------
-
-
-def test_autotune_candidates_and_defaults():
-    assert autotune.candidates_for(100) == (128,)
-    assert autotune.candidates_for(200) == (128, 256)
-    assert autotune.candidates_for(600) == (128, 256, 512)
-    assert autotune.default_blocks((100, 600)) == (128, 128)
-
-
-def test_autotune_cache_deterministic_off_tpu():
-    autotune.clear_cache()
-    try:
-        calls = []
-        b1 = autotune.tuned_blocks(
-            "op", (300, 40), kind="k",
-            compile_candidate=lambda blk: calls.append(blk))
-        b2 = autotune.tuned_blocks(
-            "op", (300, 40), kind="k",
-            compile_candidate=lambda blk: calls.append(blk))
-        assert b1 == b2 == (128, 128)
-        assert calls == [], "no search may run off-TPU"
-        key = autotune.TileKey("op", (300, 40), "float32", "k")
-        assert autotune.cache_info()[key].source == "default"
-    finally:
-        autotune.clear_cache()
-
-
-def _fake_tpu_search(monkeypatch, refuse):
-    """Run the TPU search path on the CPU: candidates in ``refuse`` are
-    refused the way the TPU compiler refuses a tile."""
-    monkeypatch.setattr(autotune, "_on_tpu", lambda: True)
-    monkeypatch.setattr(autotune, "_SEARCH_ITERS", 1)
-
-    def compile_candidate(blocks):
-        if blocks in refuse:
-            raise ValueError(f"refused {blocks}")
-        return lambda: jnp.zeros(())
-
-    return compile_candidate
-
-
-def test_autotune_search_skips_refused_candidates(monkeypatch):
-    autotune.clear_cache()
-    try:
-        compile_candidate = _fake_tpu_search(monkeypatch, {(128,), (512,)})
-        blocks = autotune.tuned_blocks("op", (600,), kind="k",
-                                       compile_candidate=compile_candidate)
-        assert blocks == (256,)
-        key = autotune.TileKey("op", (600,), "float32", "k")
-        assert autotune.cache_info()[key].source == "search"
-    finally:
-        autotune.clear_cache()
-
-
-def test_autotune_search_raises_when_every_candidate_refused(monkeypatch):
-    autotune.clear_cache()
-    try:
-        compile_candidate = _fake_tpu_search(
-            monkeypatch, {(128,), (256,), (512,)})
-        with pytest.raises(RuntimeError, match="refused every tile"):
-            autotune.tuned_blocks("op", (600,), kind="k",
-                                  compile_candidate=compile_candidate)
-        assert autotune.cache_info() == {}
-    finally:
-        autotune.clear_cache()
-
-
-def test_autotune_never_searches_under_a_trace(monkeypatch):
-    """A traced operand means the op is being staged into an outer jit:
-    the resolver takes the defaults and launches nothing."""
-    autotune.clear_cache()
-    try:
-        calls = []
-        monkeypatch.setattr(autotune, "_on_tpu", lambda: True)
-
-        def compile_candidate(blocks):
-            calls.append(blocks)
-            return lambda: jnp.zeros(())
-
-        @jax.jit
-        def traced(x):
-            blocks = autotune.tuned_blocks(
-                "op", (600,), kind="k", compile_candidate=compile_candidate,
-                operands=(x,))
-            return x * blocks[0]
-
-        assert float(traced(jnp.ones(()))) == 128.0
-        assert calls == []
-    finally:
-        autotune.clear_cache()
-
-
-def test_autotune_pin_overrides():
-    autotune.clear_cache()
-    try:
-        autotune.pin("sv_predict", (256,), (256,), kind="gaussian:d=9")
-        blocks = autotune.tuned_blocks("sv_predict", (256,),
-                                       kind="gaussian:d=9")
-        assert blocks == (256,)
-        X, SV, A = _sv_args(_rng(8), 3, 256, 9)
-        got = ops.sv_predict(X, SV, A, kind="gaussian", gamma=0.5)
-        assert_backend_parity(
-            got, ref.sv_predict_ref(X, SV, A, kind="gaussian", gamma=0.5),
-            "pinned 256 block")
-    finally:
-        autotune.clear_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -317,16 +201,14 @@ def _pallas_sub():
         backend="pallas")
 
 
-def test_ops_reuse_compiles_across_autotune_resets():
-    """Value-equal calls hit the jit cache even after the tuner's table
-    is dropped: off-TPU resolution is deterministic, so the launcher's
-    static block args — and therefore its executable — are identical."""
+def test_value_equal_ops_calls_reuse_the_compile():
+    """Value-equal calls hit the jit cache: a value-equal spec gives the
+    launcher the same static args, and so the same executable."""
     X, SV, A = _sv_args(_rng(9), 3, 200, 9)
-    ops.sv_predict(X, SV, A, gamma=0.5)          # warm (may compile)
+    ops.sv_predict(SPEC, X, SV, A)               # warm (may compile)
     with CompileCounter() as c:
-        ops.sv_predict(X, SV, A, gamma=0.5)
-        autotune.clear_cache()
-        ops.sv_predict(X, SV, A, gamma=0.5)
+        ops.sv_predict(SPEC, X, SV, A)
+        ops.sv_predict(KernelSpec("gaussian", gamma=0.5), X, SV, A)
     assert c.compiles == 0
 
 

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.rkhs import KernelSpec
 from repro.kernels import ops, ref
 
 SHAPES = [(128, 128, 8), (256, 384, 130), (100, 200, 7), (513, 129, 64),
@@ -25,7 +26,7 @@ def _data(M, N, d, dtype, seed=0):
 @pytest.mark.parametrize("kind", KINDS)
 def test_gram_matches_oracle(M, N, d, kind):
     X, Y, _, _ = _data(M, N, d, jnp.float32)
-    got = ops.gram(X, Y, kind=kind, gamma=0.5, force_pallas=True)
+    got = ops.gram(KernelSpec(kind, gamma=0.5), X, Y)
     want = ref.gram_ref(X, Y, kind=kind, gamma=0.5)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -33,7 +34,7 @@ def test_gram_matches_oracle(M, N, d, kind):
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_gram_dtypes(dtype):
     X, Y, _, _ = _data(128, 128, 16, dtype)
-    got = ops.gram(X, Y, kind="gaussian", gamma=1.0, force_pallas=True)
+    got = ops.gram(KernelSpec("gaussian", gamma=1.0), X, Y)
     want = ref.gram_ref(X, Y, kind="gaussian", gamma=1.0)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
@@ -43,7 +44,7 @@ def test_gram_dtypes(dtype):
 @pytest.mark.parametrize("kind", KINDS)
 def test_quadform_matches_oracle(M, N, d, kind):
     X, Y, a, b = _data(M, N, d, jnp.float32)
-    got = ops.quadform(X, Y, a, b, kind=kind, gamma=0.5, force_pallas=True)
+    got = ops.quadform(KernelSpec(kind, gamma=0.5), X, Y, a, b)
     want = ref.quadform_ref(X, Y, a, b, kind=kind, gamma=0.5)
     np.testing.assert_allclose(got, want, rtol=5e-4,
                                atol=5e-3 * max(1.0, abs(float(want))))
@@ -55,7 +56,7 @@ def test_rff_matches_oracle(M, N, d):
     X = jnp.asarray(rng.normal(size=(M, d)), jnp.float32)
     W = jnp.asarray(rng.normal(size=(N, d)), jnp.float32)
     b = jnp.asarray(rng.uniform(size=(N,)) * 6.28, jnp.float32)
-    got = ops.rff_features(X, W, b, force_pallas=True)
+    got = ops.rff_features(X, W, b)
     want = ref.rff_ref(X, W, b)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -68,7 +69,7 @@ def test_rff_approximates_gaussian_kernel():
     X = jnp.asarray(rng.normal(size=(16, d)), jnp.float32)
     W = jnp.asarray(rng.normal(size=(D, d)) * np.sqrt(2 * gamma), jnp.float32)
     b = jnp.asarray(rng.uniform(size=(D,)) * 2 * np.pi, jnp.float32)
-    Z = ops.rff_features(X, W, b, force_pallas=True)
+    Z = ops.rff_features(X, W, b)
     K_hat = np.asarray(Z @ Z.T)
     K = np.asarray(ref.gram_ref(X, X, kind="gaussian", gamma=gamma))
     assert np.max(np.abs(K_hat - K)) < 0.12
@@ -80,7 +81,7 @@ def test_rkhs_dist_sq_fused():
     Y = jnp.asarray(rng.normal(size=(200, 9)), jnp.float32)
     a = jnp.asarray(rng.normal(size=(130,)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(200,)), jnp.float32)
-    got = ops.rkhs_dist_sq(X, Y, a, b, kind="gaussian", gamma=0.5)
+    got = ops.rkhs_dist_sq(KernelSpec("gaussian", gamma=0.5), X, Y, a, b)
     Kxx = ref.gram_ref(X, X, gamma=0.5)
     Kyy = ref.gram_ref(Y, Y, gamma=0.5)
     Kxy = ref.gram_ref(X, Y, gamma=0.5)
@@ -91,11 +92,11 @@ def test_rkhs_dist_sq_fused():
 # --- substrate backend dispatch (core/substrate.py, DESIGN.md Sec. 8) -------
 #
 # The substrate layer's backend="pallas" routes predict / dist_to_ref /
-# divergence through ops.gram / quadform / rff_features.  These tests
-# pin the interpret-mode Pallas kernels against the substrate's
-# *reference* paths (the pure-jnp semantics in core/rkhs.py and
-# core/rff.py), tolerance-bounded, on shapes large enough that the
-# Pallas launch actually engages (>= 128, see ops._MIN_PALLAS).
+# divergence through ops.sv_predict / rkhs_dist_sq / rff_features.
+# These tests pin the interpret-mode Pallas kernels against the
+# substrate's *reference* paths (the pure-jnp semantics in core/rkhs.py
+# and core/rff.py), tolerance-bounded, on shapes large enough that the
+# substrate engages the Pallas launch (>= 128, see ops._MIN_PALLAS).
 
 
 def _sv_fixture(m=2, budget=130, d=9, seed=5):
@@ -158,10 +159,9 @@ def test_substrate_rff_features_pallas_vs_reference():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_spec_entry_points_force_pallas_vs_substrate_reference():
-    """ops.gram_spec / quadform_spec / rkhs_dist_sq_spec with the Pallas
-    path forced, against the rkhs.py reference algebra the substrates
-    use by default."""
+def test_spec_entry_points_vs_substrate_reference():
+    """ops.gram / quadform / rkhs_dist_sq on a KernelSpec, against the
+    rkhs.py reference algebra the substrates use by default."""
     from repro.core import rkhs
     from repro.core.rkhs import KernelSpec
     spec = KernelSpec("gaussian", gamma=0.7)
@@ -171,17 +171,17 @@ def test_spec_entry_points_force_pallas_vs_substrate_reference():
     a = jnp.asarray(rng.normal(size=(130,)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(150,)), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(ops.gram_spec(spec, X, Y, force_pallas=True)),
+        np.asarray(ops.gram(spec, X, Y)),
         np.asarray(rkhs.gram(spec, X, Y)), rtol=2e-5, atol=2e-5)
     want_qf = float(a @ rkhs.gram(spec, X, Y) @ b)
-    got_qf = float(ops.quadform_spec(spec, X, Y, a, b, force_pallas=True))
+    got_qf = float(ops.quadform(spec, X, Y, a, b))
     np.testing.assert_allclose(got_qf, want_qf, rtol=5e-4,
                                atol=5e-3 * max(1.0, abs(want_qf)))
     fa = rkhs.SVModel(sv=X, alpha=a, sv_id=jnp.arange(130, dtype=jnp.int32))
     fb = rkhs.SVModel(sv=Y, alpha=b,
                       sv_id=jnp.arange(130, 280, dtype=jnp.int32))
     np.testing.assert_allclose(
-        float(ops.rkhs_dist_sq_spec(spec, X, Y, a, b)),
+        float(ops.rkhs_dist_sq(spec, X, Y, a, b)),
         float(rkhs.dist_sq(spec, fa, fb)), rtol=1e-4, atol=1e-3)
 
 

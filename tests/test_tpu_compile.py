@@ -59,15 +59,13 @@ def _assert_kernel(compiled):
 
 def test_sv_predict_compiles(one_chip):
     def fn(X, SV, A):
-        return ops._sv_predict_call(X, SV, A, block_n=128, interpret=False,
-                                    **KERNEL)
+        return ops._sv_predict_call(X, SV, A, interpret=False, **KERNEL)
 
     _assert_kernel(_compile(fn, one_chip, (B, d), (B, N, d), (B, N)))
 
 
 def _quadform(X, Y, a, b):
-    return ops._quadform_call(X, Y, a, b, block_m=128, block_n=128,
-                              interpret=False, **KERNEL)
+    return ops._quadform_call(X, Y, a, b, interpret=False, **KERNEL)
 
 
 def test_quadform_compiles(one_chip):
@@ -90,8 +88,7 @@ def test_primal_step_compiles(one_chip, featurize):
         W, bias = rff if featurize else (None, None)
         return ops._primal_step_call(
             X, Yl, w, b, W, bias, scale=(2.0 / D) ** 0.5, loss="hinge",
-            eta=0.5, lam=0.01, block_m=128, featurize=featurize,
-            interpret=False)
+            eta=0.5, lam=0.01, featurize=featurize, interpret=False)
 
     shapes = [(B, d), (B,), (B, D), (B,)]
     if featurize:
@@ -101,8 +98,7 @@ def test_primal_step_compiles(one_chip, featurize):
 
 def test_gram_compiles(one_chip):
     def fn(X, Y):
-        return ops._gram_call(X, Y, block_m=128, block_n=128,
-                              interpret=False, **KERNEL)
+        return ops._gram_call(X, Y, interpret=False, **KERNEL)
 
     _assert_kernel(_compile(fn, one_chip, (N, d), (N, d)))
 
