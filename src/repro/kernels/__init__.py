@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the paper compute hot-spots.
 
-``ops`` is the public face (padding, 128-wide tiles, interpret-mode
+``ops`` is the public face (padding, launch, interpret-mode
 selection); ``fused`` holds the per-round fused kernels; ``ref`` the
 jnp oracles.
 """

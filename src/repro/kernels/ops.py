@@ -9,10 +9,12 @@ op that chooses, per quadratic-form term, because its terms' extents
 can fall on both sides of the threshold.
 
 - padding: every blocked axis is padded to a multiple of ``_LANE``
-  (128), and every kernel launches with 128-wide tiles.  Zero padding
-  is exact for the feature dim of every kernel kind; padded rows/cols
-  are cropped from outputs, and padded alpha/beta entries are zero so
-  quadform is exact.
+  (128).  The gram, rff, sv_predict and primal-step kernels pad the
+  feature dim too and launch with 128-wide tiles; quadform pads only
+  its slot axes and blocks them itself (kernels/quadform.py), with d
+  unpadded.  Zero padding is exact for the feature dim of every kernel
+  kind; padded rows/cols are cropped from outputs, and padded
+  alpha/beta entries are zero so quadform is exact.
 - interpret mode off-TPU: on a CPU backend interpret=True executes the
   kernel bodies in Python, which is how the test suite checks the TPU
   kernels' numerics; on a TPU backend they compile to Mosaic
@@ -139,13 +141,14 @@ def _rff_call(X, W, b, *, num_features, interpret):
 )
 def _quadform_call(X, Y, alpha, beta, *, kind, gamma, degree, coef0,
                    interpret):
-    Xp = _pad_to(_pad_to(X, 0, _LANE), 1, _LANE)
-    Yp = _pad_to(_pad_to(Y, 0, _LANE), 1, _LANE)
+    # the slot axes only: quadform keeps d unpadded
+    Xp = _pad_to(X, 0, _LANE)
+    Yp = _pad_to(Y, 0, _LANE)
     ap = _pad_to(alpha, 0, _LANE)
     bp = _pad_to(beta, 0, _LANE)
     return quadform_pallas(
         Xp, Yp, ap, bp, kind=kind, gamma=gamma, degree=degree, coef0=coef0,
-        block_m=_LANE, block_n=_LANE, interpret=interpret,
+        interpret=interpret,
     )
 
 

@@ -3,20 +3,41 @@
 q = alpha^T K(X, Y) beta  —  the building block of RKHS norms,
 distances, and the divergence/local-condition monitoring (Sec. 2).
 
-A naive implementation materializes the (M, N) Gram matrix in HBM only
-to immediately contract it on both sides.  This kernel streams (bm, bn)
-Gram tiles through VMEM and accumulates the scalar
+The (M, N) Gram matrix is never written to HBM: it is evaluated in
+VMEM and contracted on both sides at once, so HBM traffic is the
+operands alone, O((M + N) d).
 
-    q = sum_ij alpha_i K_ij beta_j
+Layout.  The slot axes lie on the lanes: the kernel reads X^T (d, M),
+Y^T (d, N), alpha (1, M) and beta (1, N), with d unpadded (a block
+dim equal to the array's own is legal in Mosaic).  A learner's
+(512, 8) expansion is then 16 KB in HBM, not a (512, 128) pad of
+256 KB, and for the stacked learners XLA lays the (m, tau, d) stack
+out so that the transpose is free.  The slot axes arrive padded to a
+multiple of 128 with zero coefficients (ops.py).
 
-in an fp32 accumulator, so HBM traffic is O(M d + N d) instead of
-O(M N) — on a v5e (819 GB/s HBM) this turns the divergence check from
-memory-bound to compute-bound for typical budgets.
+Blocking.  A grid step takes the whole padded extents up to
+``_BLOCK`` slots a side, so one learner's 512-slot check is one step;
+a vmap over learners adds a leading grid axis of one step per
+learner.  Within a step the X block is transposed once into VMEM
+scratch (rows on sublanes), and the Gram is walked in row strips
+against every column of the block: alpha_i K_ij accumulates
+elementwise into one (strip, bn) value, which is reduced against beta
+to a scalar once at the end of the step.  VMEM holds a block and a
+strip, never the Gram.
 
-TPU grid iterations execute sequentially, so cross-step accumulation
-into the output ref is safe; the first step initializes it.  The
-accumulator is a lane-dense (1, 128) row, not a (1, 1) scalar: the
-TPU lowering cannot store a scalar to VMEM.
+The cross term is d unrolled multiply-adds on the vector unit (the
+Gaussian kernel sums squared differences), exact float32 and
+elementwise, so a learner's floats are the same in a vmapped and an
+unbatched launch on every backend.  ``_STRIP`` rows a strip was chosen
+with tools/quadform_cost.py, which times the strip sizes on a chip.
+
+A row's floats depend only on its own learner's operands and the
+grid order within that learner, never on how many learners share the
+launch or on which operand is shared (``SVSubstrate.dist_to_ref``
+against ``dist_to_ref_each``).  TPU grid iterations run sequentially,
+so steps accumulate into the output ref; the first step initializes
+it.  The output is a lane-dense (1, 128) row, not a (1, 1) scalar:
+the TPU lowering cannot store a scalar to VMEM.
 """
 from __future__ import annotations
 
@@ -25,45 +46,59 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BM = 128
-DEFAULT_BN = 128
 _LANE = 128          # TPU lane width: lane-dense output row
+_BLOCK = 512         # largest slot block a side of one grid step
+_STRIP = 32          # Gram rows a strip; divides every slot block
 
 
-def _quadform_kernel(x_ref, y_ref, a_ref, b_ref, o_ref, *, kind: str,
-                     gamma: float, degree: int, coef0: float):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+def _slot_block(n: int) -> int:
+    """The whole padded extent up to ``_BLOCK``, else the largest
+    multiple of 128 up to ``_BLOCK`` that divides it."""
+    if n <= _BLOCK:
+        return n
+    return max(b for b in range(_LANE, _BLOCK + 1, _LANE) if n % b == 0)
 
-    x = x_ref[...].astype(jnp.float32)            # (bm, d)
-    y = y_ref[...].astype(jnp.float32)            # (bn, d)
-    a = a_ref[...].astype(jnp.float32)            # (1, bm)
-    b = b_ref[...].astype(jnp.float32)            # (1, bn)
 
-    cross = jax.lax.dot_general(
-        x, y, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
+def _strip_values(x, yt, *, kind: str, gamma: float, degree: int,
+                  coef0: float):
+    """k(x_i, y_j) for a strip: x (s, d), yt (d, bn) -> (s, bn)."""
+    acc = None
+    for k in range(x.shape[1]):
+        xk, yk = x[:, k:k + 1], yt[k:k + 1, :]
+        term = (xk - yk) ** 2 if kind == "gaussian" else xk * yk
+        acc = term if acc is None else acc + term
+    if kind == "gaussian":
+        return jnp.exp(-gamma * acc)
     if kind == "linear":
-        k = cross
-    elif kind == "poly":
-        k = (cross + coef0) ** degree
-    else:
-        xx = jnp.sum(x * x, axis=1, keepdims=True)
-        yy = jnp.sum(y * y, axis=1, keepdims=True).T
-        k = jnp.exp(-gamma * jnp.maximum(xx + yy - 2.0 * cross, 0.0))
+        return acc
+    return (acc + coef0) ** degree
 
-    partial_val = jnp.sum((a.T * k) * b)          # alpha_i K_ij beta_j over tile
 
-    @pl.when((i == 0) & (j == 0))
+def _quadform_kernel(xt_ref, yt_ref, a_ref, b_ref, o_ref, x_scr, a_scr, *,
+                     kind: str, gamma: float, degree: int, coef0: float):
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    bm, bn = x_scr.shape[0], yt_ref.shape[1]
+    x_scr[...] = xt_ref[...].astype(jnp.float32).T   # (bm, d)
+    a_scr[...] = a_ref[...].astype(jnp.float32).T    # (bm, 1)
+    yt = yt_ref[...].astype(jnp.float32)             # (d, bn)
+
+    def row_strip(s, acc):
+        rows = pl.ds(pl.multiple_of(s * _STRIP, _STRIP), _STRIP)
+        k = _strip_values(x_scr[rows, :], yt, kind=kind, gamma=gamma,
+                          degree=degree, coef0=coef0)
+        return acc + a_scr[rows, :] * k
+
+    acc = jax.lax.fori_loop(0, bm // _STRIP, row_strip,
+                            jnp.zeros((_STRIP, bn), jnp.float32))
+    b = b_ref[...].astype(jnp.float32)
     # Mosaic stores vectors only: every lane of the (1, LANE) output
     # carries the same running sum, and the wrapper reads lane 0.
-    o_ref[...] += partial_val
+    o_ref[...] += jnp.sum(jnp.sum(acc, axis=0, keepdims=True) * b)
 
 
 def quadform_pallas(
@@ -76,27 +111,30 @@ def quadform_pallas(
     gamma: float = 1.0,
     degree: int = 3,
     coef0: float = 1.0,
-    block_m: int = DEFAULT_BM,
-    block_n: int = DEFAULT_BN,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """alpha^T K(X, Y) beta; M and N pre-padded to multiples of 128
+    with zero alpha/beta entries, d unpadded."""
     M, d = X.shape
     N, _ = Y.shape
-    assert M % block_m == 0 and N % block_n == 0, (M, N, block_m, block_n)
+    assert M % _LANE == 0 and N % _LANE == 0, (M, N)
+    bm, bn = _slot_block(M), _slot_block(N)
     kernel = functools.partial(
         _quadform_kernel, kind=kind, gamma=gamma, degree=degree, coef0=coef0
     )
     out = pl.pallas_call(
         kernel,
-        grid=(M // block_m, N // block_n),
+        grid=(M // bm, N // bn),
         in_specs=[
-            pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
-            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+            pl.BlockSpec((d, bm), lambda i, j: (0, i)),
+            pl.BlockSpec((d, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bm), lambda i, j: (0, i)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, _LANE), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, _LANE), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, d), jnp.float32),
+                        pltpu.VMEM((bm, 1), jnp.float32)],
         interpret=interpret,
-    )(X, Y, alpha.reshape(1, M), beta.reshape(1, N))
+    )(X.T, Y.T, alpha.reshape(1, M), beta.reshape(1, N))
     return out[0, 0]

@@ -1,5 +1,6 @@
 """Per-kernel validation: Pallas (interpret mode on CPU) vs pure-jnp
 oracle, swept over shapes and dtypes."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -40,7 +41,14 @@ def test_gram_dtypes(dtype):
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("M,N,d", SHAPES)
+# quadform's own extents besides SHAPES: d = 1, 8, 13 and 24, M != N,
+# slot extents that are not multiples of 128 (zero-padded coefficients),
+# and more than one (_BLOCK-slot) grid step a side
+QUADFORM_SHAPES = SHAPES + [(200, 640, 1), (640, 200, 8), (200, 200, 13),
+                            (1024, 256, 8), (256, 200, 24)]
+
+
+@pytest.mark.parametrize("M,N,d", QUADFORM_SHAPES)
 @pytest.mark.parametrize("kind", KINDS)
 def test_quadform_matches_oracle(M, N, d, kind):
     X, Y, a, b = _data(M, N, d, jnp.float32)
@@ -48,6 +56,28 @@ def test_quadform_matches_oracle(M, N, d, kind):
     want = ref.quadform_ref(X, Y, a, b, kind=kind, gamma=0.5)
     np.testing.assert_allclose(got, want, rtol=5e-4,
                                atol=5e-3 * max(1.0, abs(float(want))))
+
+
+@pytest.mark.parametrize("shared", [False, True],
+                         ids=["batched_ref", "shared_ref"])
+def test_quadform_vmap_bitwise_per_learner(shared):
+    """A learner's quadform floats do not depend on the batch around it
+    or on whether the other operand is batched or shared."""
+    rng = np.random.default_rng(11)
+    B, M, N, d = 3, 256, 200, 8
+    spec = KernelSpec("gaussian", gamma=0.3)
+    X = jnp.asarray(rng.normal(size=(B, M, d)), jnp.float32)
+    a = jnp.asarray(rng.normal(size=(B, M)), jnp.float32)
+    Y = jnp.asarray(rng.normal(size=(B, N, d)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(B, N)), jnp.float32)
+    if shared:
+        Y, b = Y[0], b[0]
+        got = jax.vmap(lambda x, al: ops.quadform(spec, x, Y, al, b))(X, a)
+        want = [ops.quadform(spec, X[i], Y, a[i], b) for i in range(B)]
+    else:
+        got = jax.vmap(lambda *z: ops.quadform(spec, *z))(X, Y, a, b)
+        want = [ops.quadform(spec, X[i], Y[i], a[i], b[i]) for i in range(B)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("M,N,d", SHAPES)
@@ -138,6 +168,17 @@ def test_substrate_dist_to_ref_pallas_vs_reference():
     want = s_ref.dist_to_ref(models, ref_model)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=5e-4, atol=5e-3)
+
+
+def test_substrate_dist_to_ref_each_bitwise_dist_to_ref():
+    """The mesh engine's contract: ``dist_to_ref_each`` against a
+    broadcast reference gives ``dist_to_ref``'s floats bit for bit."""
+    _, s_pal, models, ref_model, _ = _sv_fixture(m=4, budget=256, d=8)
+    stacked = jax.tree.map(lambda r: jnp.broadcast_to(r, (4,) + r.shape),
+                           ref_model)
+    got = jax.jit(s_pal.dist_to_ref_each)(models, stacked)
+    want = jax.jit(s_pal.dist_to_ref)(models, ref_model)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_substrate_divergence_pallas_vs_reference():

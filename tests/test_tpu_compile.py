@@ -12,7 +12,9 @@ topology — no chip needed.  A compiled kernel shows up as a
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
 """
+import base64
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +79,59 @@ def test_quadform_vmapped_over_learners_compiles(one_chip):
     against the shared reference (``SVSubstrate.dist_to_ref``)."""
     fn = jax.vmap(_quadform, in_axes=(0, None, 0, None))
     _assert_kernel(_compile(fn, one_chip, (B, N, d), (N, d), (B, N), (N,)))
+
+
+@pytest.mark.parametrize("vmapped", [False, True],
+                         ids=["unbatched", "vmapped"])
+@pytest.mark.parametrize("dim", [1, 13, 24])
+def test_quadform_compiles_at_dim(one_chip, dim, vmapped):
+    """quadform keeps d unpadded and transposes a (d, slots) block in
+    VMEM: it must lower at feature counts that are not a multiple of
+    the 8 sublanes, and past SUSY's 8."""
+    if vmapped:
+        fn = jax.vmap(_quadform, in_axes=(0, None, 0, None))
+        shapes = (B, N, dim), (N, dim), (B, N), (N,)
+    else:
+        fn = _quadform
+        shapes = (N, dim), (N, dim), (N,), (N,)
+    _assert_kernel(_compile(fn, one_chip, *shapes))
+
+
+def test_sv_dynamic_check_compiles_three_quadform_launches(one_chip,
+                                                          monkeypatch):
+    """The dynamic protocol's check, ``SVSubstrate.dist_to_ref`` over m
+    learners of N slots: exactly three kernel launches (||f_i||^2,
+    <f_i, r>, ||r||^2), each ``_quadform_kernel`` by the first
+    ``*_kernel`` name in its Mosaic body (the name the benchmark's
+    ``quadform_roofline`` finds), and no pad widens the (m, N, d)
+    support-vector stack to 128 lanes: d stays unpadded."""
+    from repro.core.learners import LearnerConfig
+    from repro.core.rkhs import KernelSpec, SVModel
+    from repro.core.substrate import SVSubstrate
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    lcfg = LearnerConfig(algo="kernel_sgd", budget=N, dim=d,
+                         kernel=KernelSpec(**KERNEL))
+    sub = SVSubstrate(lcfg=lcfg, backend="pallas")
+
+    def fn(sv, alpha, ids, ref_sv, ref_alpha, ref_ids):
+        return sub.dist_to_ref(SVModel(sv, alpha, ids),
+                               SVModel(ref_sv, ref_alpha, ref_ids))
+
+    shapes = [((B, N, d), jnp.float32), ((B, N), jnp.float32),
+              ((B, N), jnp.int32), ((N, d), jnp.float32),
+              ((N,), jnp.float32), ((N,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    for line in calls:
+        body = base64.b64decode(re.search(r'"body":"([^"]+)"', line).group(1))
+        names = re.findall(rb"([A-Za-z_][A-Za-z0-9_]*_kernel)(?![A-Za-z0-9_])",
+                           body)
+        assert names[0] == b"_quadform_kernel"
+    assert f"f32[{B},{N},128]" not in text
 
 
 @pytest.mark.parametrize("featurize", [True, False],
